@@ -141,17 +141,18 @@ bench-roofline:
 	    --json BENCH_engine.json
 
 # engine hot-path throughput (events/sec per strategy) + the scale axis
-# (512-client scenario single-device and client-sharded on a forced
-# multi-device host mesh, subprocess) + the federated-LM path
+# (512-client scenario on one device) + the federated-LM path
 # (tiny_lm with/without the polyline codec) + the fault-plane
 # degradation curve (0/5%/20% fault pressure) + the population plane
 # (streaming rounds at 1k/100k/1M clients, flat-memory pin) + the
 # topology plane (flat vs hierarchical ev/s, per-link-class wire bytes,
 # compensation vs staleness, degenerate bitwise pin re-checked) +
 # machine-readable JSON for cross-PR perf tracking
+# (the client-sharded row, `benchmarks.run engine_scaled engine_sharded`,
+# needs a process that sees at least 2 devices)
 bench-engine:
 	$(PY) -m benchmarks.run engine engine_scaled engine_lm \
-	    engine_faults engine_sharded engine_population \
+	    engine_faults engine_population \
 	    engine_topology $(if $(SMOKE),--smoke) --json BENCH_engine.json
 
 # serving-plane latency under open-loop Poisson load, from spec-hash-

@@ -46,22 +46,16 @@ per-strategy spec hashes) so the perf trajectory is machine-readable and
 attributable across PRs.
 
 Scale axis: ``engine_scaled`` measures the 512-client workload
-(``BENCH_SCALED_CLIENTS`` overrides, e.g. 2048) on the current device
-topology; ``engine_sharded`` re-runs it under a host mesh with a forced
-multi-device count in a subprocess (the device count is fixed at first
-jax init, so the sharded measurement needs its own process) and records
-the measured sharded events/sec next to the single-device number.
-``--devices N`` forces N host devices for this process (must come from a
-fresh process); ``--scaled-mesh NAME`` runs the scaled scenario under a
-named mesh (launch/mesh.py grammar) — both are what the subprocess uses.
+(``BENCH_SCALED_CLIENTS`` overrides, e.g. 2048) on one device;
+``engine_sharded`` re-runs it in the same process under a host mesh over
+every visible device and records the sharded events/sec next to the
+single-device number.  It raises with fewer than two devices.
 """
 from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
-import tempfile
 import time
 from typing import Any, Dict, List
 
@@ -256,11 +250,6 @@ def engine():
                                for r in JSON_DOC["results"]}
 
 
-#: named mesh for the scaled scenario (set by --scaled-mesh; the
-#: engine_sharded subprocess passes "host")
-SCALED_MESH: List[str] = [None]
-
-
 def _scaled_spec(mesh=None):
     """The scale-axis scenario: >= 512 clients, a larger per-round client
     fan-out, reduced budget (the per-event cost is what's measured)."""
@@ -278,11 +267,13 @@ def _scaled_spec(mesh=None):
 
 
 def engine_scaled():
-    """Scaled FedAT workload (512+ clients, clients_per_round=32) on the
-    current device topology — the measured max-workload point.  Under
-    ``--scaled-mesh host`` with forced devices this is the client-sharded
-    round step; on one device it is the single-device fused step."""
-    mesh = SCALED_MESH[0]
+    """Scaled FedAT workload (512+ clients, clients_per_round=32) on one
+    device — the measured max-workload point (``engine_sharded`` runs the
+    same scenario client-sharded)."""
+    _measure_scaled(None)
+
+
+def _measure_scaled(mesh):
     spec = _scaled_spec(mesh)
     n_updates = spec.engine.total_updates
     warm = spec.with_overrides({"engine.total_updates": 3})
@@ -661,28 +652,17 @@ def engine_topology():
 
 
 def engine_sharded():
-    """The scaled scenario under a multi-device host mesh, measured in a
-    subprocess with ``--xla_force_host_platform_device_count`` (the only
-    way to change the device count after jax initialized here).  Merges
-    the child's record into the JSON doc and emits the sharded-vs-single
-    throughput ratio when both measurements exist."""
-    n_dev = int(os.environ.get("BENCH_SHARD_DEVICES", "2"))
-    with tempfile.TemporaryDirectory() as td:
-        out = os.path.join(td, "sharded.json")
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.run", "engine_scaled",
-             "--devices", str(n_dev), "--scaled-mesh", "host",
-             "--json", out],
-            capture_output=True, text=True, timeout=1800,
-            env={**os.environ, "JAX_PLATFORMS": "cpu"})
-        if proc.returncode != 0:
-            emit("engine/sharded", 0.0, "error=subprocess_failed")
-            print(proc.stderr[-2000:], file=sys.stderr)
-            return
-        with open(out) as f:
-            child = json.load(f)
-    rec = child["results"][-1]
-    JSON_DOC["results"].append(rec)
+    """The scaled scenario client-sharded over every device this process
+    sees (``mesh.kind=host``), next to the single-device number when
+    ``engine_scaled`` ran first.  One process drives all devices; with
+    fewer than two there is nothing to shard over, which is an error."""
+    n_dev = len(jax.devices())
+    if n_dev < 2:
+        raise RuntimeError(
+            f"engine_sharded needs at least 2 devices, this process sees "
+            f"{n_dev} ({jax.devices()[0].platform})")
+    _measure_scaled("host")
+    rec = JSON_DOC["results"][-1]
     single = [r for r in JSON_DOC["results"]
               if r.get("scenario", "").startswith("scaled")
               and r.get("mesh") == "single"]
@@ -701,9 +681,9 @@ SMOKE: List[bool] = [False]
 def roofline():
     """Kernel roofline (benchmarks/roofline.kernel_roofline): achieved
     FLOP/s and % of the machine roof per kernel-layer entry point,
-    recorded into the JSON doc next to the engine rows.  The roof is the
-    v5e datasheet on TPU and calibrated in place elsewhere, so CPU CI
-    tracks a real ceiling."""
+    recorded into the JSON doc next to the engine rows.  The roof comes
+    from roofline.PEAKS on TPU and is calibrated in place elsewhere, so
+    CPU CI tracks a real ceiling."""
     from benchmarks.roofline import kernel_roofline
     doc = kernel_roofline(smoke=SMOKE[0])
     m = doc["machine"]
@@ -719,9 +699,12 @@ def roofline():
 
 
 def kernels():
-    """Kernel microbenches (interpret mode: correctness-path timing only)."""
+    """Kernel microbenches for the main-path kernels (codec, flash
+    attention); Pallas runs compiled on a TPU and interpreted elsewhere,
+    so the derived column names the backend."""
     from repro.kernels import ops
     key = jax.random.PRNGKey(0)
+    backend = f"backend={jax.default_backend()}"
 
     def bench(fn, *args, n=3):
         fn(*args)  # compile
@@ -732,27 +715,15 @@ def kernels():
 
     x = jax.random.normal(key, (262_144,))
     us = bench(lambda a: ops.compress(a, 8), x)
-    emit("kernels/codec_compress_256k", us, "interpret=True")
+    emit("kernels/codec_compress_256k", us, backend)
     q, s = ops.compress(x, 8)
     us = bench(lambda a, b: ops.decompress(a, b, (262_144,)), q, s)
-    emit("kernels/codec_decompress_256k", us, "interpret=True")
+    emit("kernels/codec_decompress_256k", us, backend)
 
     q4 = jax.random.normal(key, (1, 256, 4, 64))
     k4 = jax.random.normal(key, (1, 256, 4, 64))
     us = bench(lambda a, b, c: ops.flash_attention(a, b, c), q4, k4, k4)
-    emit("kernels/flash_attn_256", us, "interpret=True")
-
-    r = jax.random.normal(key, (4, 256, 64))
-    lw = -jnp.exp(jax.random.normal(key, (4, 256, 64)))
-    u = jax.random.normal(key, (4, 64))
-    us = bench(lambda a, b, c, d, e: ops.wkv6(a, b, c, d, e), r, r, r, lw, u)
-    emit("kernels/wkv6_256", us, "interpret=True")
-
-    xs = jax.random.normal(key, (4, 256, 64))
-    bm = jax.random.normal(key, (4, 256, 32))
-    da = -jnp.abs(jax.random.normal(key, (4, 256, 1)))
-    us = bench(lambda a, b, c, d: ops.ssd(a, b, c, d), xs, bm, bm, da)
-    emit("kernels/ssd_256", us, "interpret=True")
+    emit("kernels/flash_attn_256", us, backend)
 
 
 def trainer():
@@ -835,19 +806,9 @@ def _pop_flag(argv: List[str], flag: str):
 
 def main() -> None:
     argv, json_path = _pop_flag(sys.argv[1:], "--json")
-    argv, devices = _pop_flag(argv, "--devices")
-    argv, scaled_mesh = _pop_flag(argv, "--scaled-mesh")
     if "--smoke" in argv:
         argv = [a for a in argv if a != "--smoke"]
         SMOKE[0] = True
-    if devices:
-        # must run before anything touches the backend: jax is imported
-        # above but stays uninitialized until the first device query
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={devices}").strip()
-    if scaled_mesh:
-        SCALED_MESH[0] = scaled_mesh
     which = argv or [t for t in ALL if t != "engine_sharded"]
     if json_path and not any(t in _JSON_TARGETS for t in which):
         sys.exit(f"--json records the structured targets "
@@ -861,4 +822,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

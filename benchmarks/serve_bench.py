@@ -152,4 +152,6 @@ def main(argv: Optional[List[str]] = None) -> Dict[str, Any]:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -230,4 +230,6 @@ def main(argv: List[str] = None) -> List[api.Result]:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -60,9 +60,9 @@ class Codec(abc.ABC):
     #: can run inside the fused round step (core/executor.py).  All
     #: registered codecs are in-graph: ``none`` is the identity,
     #: ``polyline`` rounds with jnp ops, ``quantize*`` runs the Pallas
-    #: kernel (interpret mode on CPU).  A future host-side codec (e.g.
-    #: one marshalling through Python bytes) must set this False and will
-    #: be rejected by the fused step with a clear error.
+    #: kernel.  A future host-side codec (e.g. one marshalling through
+    #: Python bytes) must set this False and will be rejected by the
+    #: fused step with a clear error.
     in_graph: bool = True
 
     def lossy(self, params: Any) -> Any:
@@ -158,11 +158,12 @@ class QuantizeCodec(Codec):
     """Blockwise fixed-point quantization, Pallas-kernel lossy step.
 
     Wire format and byte accounting come from compress/quantize.py; the
-    in-graph roundtrip runs the TPU kernel in kernels/polyline_codec.py
-    (``interpret=True`` executes it on CPU).
+    in-graph roundtrip runs the TPU kernel in kernels/polyline_codec.py.
+    ``interpret=None`` leaves the choice to ``kernels.ops.interpret_mode``
+    (compiled on a TPU); tests pass ``True``.
     """
 
-    def __init__(self, bits: int = 8, interpret: bool = True):
+    def __init__(self, bits: int = 8, interpret: Optional[bool] = None):
         if not 2 <= bits <= 16:
             # the wire dtype is int8/int16; wider widths would silently
             # wrap when q is cast (quantize.compress)

@@ -29,8 +29,6 @@ import enum
 import pickle
 from typing import Any, Optional
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import faults as faults_mod
@@ -203,10 +201,10 @@ def _apply_engine_snapshot(snap: dict, ctx: EngineContext,
         tier_of, members, lat = host["tm"]
         env.tm = tiering.TierMap(tier_of=tier_of, members=list(members),
                                  latencies=lat)
-    # jnp.asarray preserves shapes/dtypes, so the restored state hits the
-    # executor's existing compile-cache entries — zero extra recompiles
-    strategy.restore(jax.tree.map(jnp.asarray, snap["dev"]),
-                     host["strategy"])
+    # same shapes, dtypes and placement as the live state, so the restored
+    # state hits the executor's existing compile-cache entries — zero
+    # extra recompiles
+    strategy.restore(env.replicate(snap["dev"]), host["strategy"])
 
 
 def run_engine(env: SimEnv, strategy: ServerStrategy, cfg: EngineConfig,

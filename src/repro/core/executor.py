@@ -24,8 +24,8 @@ Eq. 4 / Eq. 3 aggregation as a swarm of tiny un-jitted dispatches.  The
 * **Fused round step** — downlink codec ``lossy`` → gather → vmapped
   local train → uplink ``lossy`` → Eq. 4 intra-tier average →
   ``tier_models.at[m].set`` → Eq. 3 cross-tier aggregation run as one
-  jitted call, with buffer donation for the server-state arguments on
-  backends that support it (TPU/GPU; CPU ignores donation).
+  jitted call, with buffer donation for the server-state arguments
+  (every backend, the CPU included, updates them in place).
 
 Bitwise parity with the eager seed loops constrains what may live inside
 the fused program: XLA rewrites division into reciprocal-multiply and
@@ -50,14 +50,15 @@ retraces across a dropout-laden run.
 
 **Client sharding on a device mesh** (DESIGN.md §Scale-mapping).  When the
 environment carries a mesh whose ``data`` axis has size D > 1, the
-per-round client stack is split over that axis: the vmapped local train +
-pinned uplink ``lossy`` + partial Eq. 4 weighted sum run under
-``shard_map`` (each device trains K/D clients), and one ``psum`` over
-``data`` completes the tier model.  Everything outside that leg — the
-downlink ``lossy`` on the replicated global model, the in-graph gather
-over the (client-sharded) resident train stacks, the tier-slot scatter,
-and the Eq. 3 cross-tier average — stays in the auto-sharded (GSPMD)
-region of the same jitted program.  ``clients_per_round`` must be a
+per-round client stack is split over that axis: the downlink ``lossy``
+(each device on its own replica of the global model — GSPMD cannot
+partition a Pallas kernel), the vmapped local train + pinned uplink
+``lossy`` + partial Eq. 4 weighted sum run under ``shard_map`` (each
+device trains K/D clients), and one ``psum`` over ``data`` completes the
+tier model.  Everything outside that leg — the in-graph gather over the
+(client-sharded) resident train stacks, the tier-slot scatter, and the
+Eq. 3 cross-tier average — stays in the auto-sharded (GSPMD) region of
+the same jitted program.  ``clients_per_round`` must be a
 multiple of D (checked at :class:`~repro.core.simulation.SimEnv` build).
 
 Parity contract across the mesh dimension: with D == 1 (no mesh, or a
@@ -76,17 +77,10 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import aggregation
 from repro.runtime import sharding as shd
-
-
-def _donate(argnums: Tuple[int, ...]) -> Tuple[int, ...]:
-    """Donate server-state buffers where the backend implements donation
-    (in-place updates instead of fresh allocations); CPU would only warn."""
-    return argnums if jax.default_backend() != "cpu" else ()
 
 
 def _pin(tree: Any) -> Any:
@@ -269,18 +263,22 @@ class RoundExecutor:
 
     # -- client-sharded leg (mesh data axis, D > 1) ---------------------
     def _train_psum(self, update, lossy):
-        """The shard_map'd leg of a sharded round: vmapped local train over
-        the K/D shard-local clients, pinned uplink ``lossy``, partial Eq. 4
+        """The shard_map'd leg of a sharded round: pinned downlink
+        ``lossy`` of the replicated model, vmapped local train over the
+        K/D shard-local clients, pinned uplink ``lossy``, partial Eq. 4
         weighted sum (same barrier-on-product rounding as
         :func:`~repro.core.aggregation.weighted_average`), then one
         ``psum`` over ``data`` completes the weighted tier average.
+        Both links' codec runs in here because GSPMD cannot partition a
+        Pallas (Mosaic) kernel: each device encodes its own replica.
 
         ``w_intra`` arrives already normalized (host-side, exactly as in
         the single-device step), so the psum of shard-partial sums *is*
         the full weighted average; padded zero-weight slots stay exactly
         neutral on whichever shard they land.
         """
-        def body(w_sent, batch, keys, w_intra):
+        def body(w, batch, keys, w_intra):
+            w_sent = _pin(lossy(w)) if lossy is not None else w
             client_params, _ = update(w_sent, batch, keys)
             client_params = (_pin(lossy(_pin(client_params)))
                              if lossy is not None else _pin(client_params))
@@ -296,11 +294,14 @@ class RoundExecutor:
             return jax.tree.map(lambda x: jax.lax.psum(x, "data"), sums)
 
         # clients split over "data"; unmentioned mesh axes (model, pod)
-        # see replicated inputs, so the P() outputs are replicated too
-        # (check_rep can't prove that through the psum, hence False).
-        return shard_map(body, self.mesh,
-                         in_specs=(P(), P("data"), P("data"), P("data")),
-                         out_specs=P(), check_rep=False)
+        # see replicated inputs, and the psum makes the P() outputs
+        # replicated too.  check_vma stays off: under it, autodiff would
+        # psum each client's gradient of the replicated model across
+        # shards, and the Pallas codec kernel does not trace with
+        # varying-axis types.
+        return jax.shard_map(body, mesh=self.mesh,
+                             in_specs=(P(), P("data"), P("data"), P("data")),
+                             out_specs=P(), check_vma=False)
 
     def _tier_place(self, tier_models):
         """Optionally pin the (M, ...) tier stack to the pod (tier) axis
@@ -321,19 +322,17 @@ class RoundExecutor:
         env = self.env
         update = env.update_fn_raw if use_prox else env.update_fn_noprox_raw
         train = self._train_psum(update, codec.lossy)
-        lossy = codec.lossy
 
         def step(w_global, tier_models, m, data, w_intra, w_cross, keys):
             self._bump(key)
-            w_sent = _pin(lossy(w_global))
             tier_model = _pin(
-                train(w_sent, self._select(data), keys, w_intra))
+                train(w_global, self._select(data), keys, w_intra))
             tier_models = self._tier_place(jax.tree.map(
                 lambda s, nw: s.at[m].set(nw), tier_models, tier_model))
             w_global = aggregation.weighted_average(tier_models, w_cross)
             return w_global, tier_models
 
-        self._steps[key] = jax.jit(step, donate_argnums=_donate((0, 1)))
+        self._steps[key] = jax.jit(step, donate_argnums=(0, 1))
         return self._steps[key]
 
     def _fedavg_step_sharded(self, codec=None):
@@ -348,10 +347,9 @@ class RoundExecutor:
 
         def step(w, data, w_intra, keys):
             self._bump(key)
-            w_in = w if codec is None else _pin(codec.lossy(w))
-            return train(w_in, self._select(data), keys, w_intra)
+            return train(w, self._select(data), keys, w_intra)
 
-        self._steps[key] = jax.jit(step, donate_argnums=_donate((0,)))
+        self._steps[key] = jax.jit(step, donate_argnums=(0,))
         return self._steps[key]
 
     # -- single-device steps (and the D == 1 path under any mesh) -------
@@ -378,7 +376,7 @@ class RoundExecutor:
             w_global = aggregation.weighted_average(tier_models, w_cross)
             return w_global, tier_models
 
-        self._steps[key] = jax.jit(step, donate_argnums=_donate((0, 1)))
+        self._steps[key] = jax.jit(step, donate_argnums=(0, 1))
         return self._steps[key]
 
     def _fedat_topology_step(self, codecs, use_prox: bool):
@@ -453,7 +451,7 @@ class RoundExecutor:
                                     dispatch, w_new)
             return w_new, silo_models, dispatch
 
-        self._steps[key] = jax.jit(step, donate_argnums=_donate((1, 2)))
+        self._steps[key] = jax.jit(step, donate_argnums=(1, 2))
         return self._steps[key]
 
     def _fedat_step_gated(self, codec, use_prox: bool, gate):
@@ -499,7 +497,7 @@ class RoundExecutor:
             w_global = aggregation.weighted_average(tier_models, w_cross)
             return w_global, tier_models
 
-        self._steps[key] = jax.jit(step, donate_argnums=_donate((0, 1)))
+        self._steps[key] = jax.jit(step, donate_argnums=(0, 1))
         return self._steps[key]
 
     def _fedavg_step(self, codec=None):
@@ -523,7 +521,7 @@ class RoundExecutor:
                 client_params = _pin(codec.lossy(_pin(client_params)))
             return aggregation.weighted_average(_pin(client_params), w_intra)
 
-        self._steps[key] = jax.jit(step, donate_argnums=_donate((0,)))
+        self._steps[key] = jax.jit(step, donate_argnums=(0,))
         return self._steps[key]
 
     def _fedavg_step_gated(self, codec, gate):
@@ -587,7 +585,7 @@ class RoundExecutor:
                               + jax.lax.optimization_barrier(c_loc * l)),
                 w, client_w)
 
-        self._steps[key] = jax.jit(step, donate_argnums=_donate((0,)))
+        self._steps[key] = jax.jit(step, donate_argnums=(0,))
         return self._steps[key]
 
     # ------------------------------------------------------------------
@@ -605,7 +603,7 @@ class RoundExecutor:
         ``(w_global, tier_models)``.
 
         Donation contract: the server-state arguments (``w_global``,
-        ``tier_models``) may be donated on TPU/GPU — callers must pass
+        ``tier_models``) are donated — callers must pass
         buffers they own (strategies copy ``env.params0`` at bind time)
         and replace their references with the returned values.  The same
         contract holds for the sharded step: shard_map does not change
@@ -646,7 +644,7 @@ class RoundExecutor:
         refreshed to the new global in-graph (the silo re-fetches on its
         next round; resample/blackout paths refresh it eagerly instead).
 
-        Donation: ``silo_models``/``dispatch`` may be donated (TPU/GPU);
+        Donation: ``silo_models``/``dispatch`` are donated;
         ``w_global`` is never donated — the compensation term reads it
         next to the dispatch snapshot that may alias it.
         """

@@ -18,6 +18,7 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import faults as faults_mod
 from repro.core import population as population_mod
@@ -226,7 +227,7 @@ class SimEnv:
         # model init + jitted client update / eval — all built from the
         # registry's bound FLModel over arbitrary pytree params
         key = jax.random.PRNGKey(sc.seed)
-        self.params0 = self.model.init_params(key)
+        self.params0 = self.replicate(self.model.init_params(key))
         self.apply_fn = self.model.apply
         # raw (un-jitted) update bodies compose inside the fused round
         # step (core/executor.py); jitting the same bodies gives the
@@ -249,10 +250,8 @@ class SimEnv:
         # device once; per-event selection is an in-graph gather
         # (core/executor.py), never a host->device copy.  Under a mesh the
         # stacks shard along the client axis (logical "clients" ->
-        # physical "data", runtime/sharding.py) when the client count
-        # divides evenly; otherwise they stay replicated — the gather runs
-        # in the auto-sharded region, so placement is a perf choice, not a
-        # correctness one.
+        # physical "data", runtime/sharding.py); the gather runs in the
+        # auto-sharded region.
         # (the streaming plane has no resident stacks: the executor
         # uploads one fixed-shape K-client batch per round instead)
         self.train_dev = (None if self.train is None else
@@ -261,11 +260,29 @@ class SimEnv:
         self._test_dev = None
         self._executor = None
 
+    def replicate(self, tree):
+        """Server state on this env's devices: replicated over the mesh, so
+        the fused steps see the same input placement on the first call as
+        on every later one (a single-device input would retrace the
+        sharded step once its mesh-placed outputs come back)."""
+        if self.mesh is None:
+            return jax.tree.map(jnp.asarray, tree)
+        return jax.device_put(tree, NamedSharding(self.mesh, P()))
+
     def _place_stack(self, arr: np.ndarray):
-        """Upload one (n_clients, ...) train stack, client-sharded when the
-        mesh's data axis divides the client count."""
-        if self.mesh is None or self.sc.n_clients % self.data_axis:
+        """Upload one (n_clients, ...) train stack, client-sharded over the
+        mesh's data axis.  The client count must divide over that axis:
+        a silently replicated stack would put every client's data on
+        every device."""
+        if self.mesh is None:
             return jnp.asarray(arr)
+        if arr.shape[0] % self.data_axis:
+            raise ValueError(
+                f"the resident train stack has {arr.shape[0]} clients, "
+                f"which does not split over the mesh data axis (size "
+                f"{self.data_axis}); use a data.n_clients that is a "
+                f"multiple of {self.data_axis}, or population.plane="
+                f"streaming (no resident stack)")
         place = sharding.logical_sharding(
             ("clients",) + (None,) * (arr.ndim - 1), self.mesh)
         return jax.device_put(arr, place)

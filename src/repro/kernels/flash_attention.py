@@ -42,12 +42,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, causal: bool, window,
 
     def body(kb, carry):
         acc, m, l = carry
-        # NB: a bare int in the pl.load index tuple breaks the interpret-mode
-        # discharge rule on jax 0.4.x — use a length-1 dslice and squeeze.
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(kb * BK, BK),
-                            slice(None)))[0].astype(jnp.float32)   # (BK, hd)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(kb * BK, BK),
-                            slice(None)))[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(kb * BK, BK), :].astype(jnp.float32)  # (BK, hd)
+        v = v_ref[0, pl.ds(kb * BK, BK), :].astype(jnp.float32)
         s = q @ k.T                                        # (BQ, BK)
         k_pos = kb * BK + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 1)
         mask = k_pos < seq_len

@@ -1,8 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handles shape massaging (padding to tile multiples, flattening batch/head
-dims), exposes ``interpret=`` for CPU validation, and provides ``use_ref``
-fallbacks so the same call sites run on non-TPU backends.
+dims) and decides interpret mode in one place, :func:`interpret_mode`:
+every wrapper takes ``interpret=None`` and resolves it from the backend
+the code observes — the compiled kernel on a TPU, the Pallas interpreter
+everywhere else.  Tests pass ``interpret=True`` explicitly.
 """
 from __future__ import annotations
 
@@ -30,23 +32,34 @@ def _pad_to(x: jax.Array, axis: int, mult: int):
     return jnp.pad(x, pad), n
 
 
+def interpret_mode(interpret: Optional[bool] = None) -> bool:
+    """Whether a Pallas kernel runs in interpret mode.  ``None`` (every
+    wrapper's default) decides from the backend: compiled on a TPU, so
+    the main path there never interprets; interpreted elsewhere, where
+    the TPU kernels cannot lower.  An explicit bool wins."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
+
+
 # --- codec -------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
-def compress(x: jax.Array, bits: int = 8, interpret: bool = True):
-    """x: any shape -> (q (nb,256) int, scale (nb,1) f32, orig size)."""
+def compress(x: jax.Array, bits: int = 8, interpret: Optional[bool] = None):
+    """x: any shape -> (q (nb,256) int, scale (nb,1) f32)."""
     flat = x.reshape(-1).astype(jnp.float32)
-    n = flat.shape[0]
     tile = codec.BLOCK * codec.TILE_B
     flat, _ = _pad_to(flat, 0, tile)
     blocks = flat.reshape(-1, codec.BLOCK)
-    q, scale = codec.compress_blocks(blocks, bits, interpret=interpret)
-    return q, scale
+    return codec.compress_blocks(blocks, bits,
+                                 interpret=interpret_mode(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "interpret"))
-def decompress(q, scale, shape: Tuple[int, ...], interpret: bool = True):
-    blocks = codec.decompress_blocks(q, scale, interpret=interpret)
+def decompress(q, scale, shape: Tuple[int, ...],
+               interpret: Optional[bool] = None):
+    blocks = codec.decompress_blocks(q, scale,
+                                     interpret=interpret_mode(interpret))
     n = 1
     for s in shape:
         n *= s
@@ -58,9 +71,36 @@ def decompress(q, scale, shape: Tuple[int, ...], interpret: bool = True):
 @functools.partial(jax.jit,
                    static_argnames=("causal", "window", "interpret"))
 def flash_attention(q, k, v, causal: bool = True,
-                    window: Optional[int] = None, interpret: bool = True):
+                    window: Optional[int] = None,
+                    interpret: Optional[bool] = None):
     """q: (B, S, H, hd); k/v: (B, T, KV, hd) with H % KV == 0 (GQA).
-    Returns (B, S, H, hd)."""
+    Returns (B, S, H, hd).
+
+    The forward pass is the Pallas kernel.  The kernel has no backward of
+    its own, so the gradient is the VJP of :func:`blocked_attention`
+    (same masks, same math), recomputed from the saved inputs."""
+    return _flash(q, k, v, causal, window, interpret_mode(interpret))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, window, interpret):
+    return _flash_forward(q, k, v, causal, window, interpret)
+
+
+def _flash_fwd(q, k, v, causal, window, interpret):
+    return _flash_forward(q, k, v, causal, window, interpret), (q, k, v)
+
+
+def _flash_bwd(causal, window, interpret, res, g):
+    _, vjp = jax.vjp(functools.partial(blocked_attention, causal=causal,
+                                       window=window), *res)
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _flash_forward(q, k, v, causal, window, interpret):
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
@@ -174,29 +214,41 @@ def attention(q, k, v, causal: bool = True, window: Optional[int] = None,
                      f"auto | pallas | pallas_interpret | blocked")
 
 
-# --- wkv6 ---------------------------------------------------------------------
+# --- chunked scans (no model calls these yet) -------------------------------
+
+def _require_interpret(name: str, why: str, interpret: Optional[bool]):
+    """The chunked-scan kernels do not compile for TPU v5e, so they run
+    only in interpret mode; asking for the compiled kernel raises."""
+    if not interpret_mode(interpret):
+        raise NotImplementedError(
+            f"the {name} Pallas kernel does not compile for TPU ({why}); "
+            f"it runs only with interpret=True")
+
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6(r, k, v, logw, u, chunk: int = 64, interpret: bool = True):
+def wkv6(r, k, v, logw, u, chunk: int = 64, interpret: Optional[bool] = None):
     """r/k/v/logw: (BH, S, N); u: (BH, N) -> y (BH, S, N)."""
+    _require_interpret(
+        "wkv6", "the (1, N) block of u breaks the (8, 128) tiling rule",
+        interpret)
     S = r.shape[1]
     rp, _ = _pad_to(r, 1, chunk)
     kp, _ = _pad_to(k, 1, chunk)      # k = 0 on padding: no state effect
     vp, _ = _pad_to(v, 1, chunk)
     lp, _ = _pad_to(logw, 1, chunk)   # logw = 0: decay 1 on padding
-    y = rwkv6_scan.wkv6(rp, kp, vp, lp, u, chunk=chunk, interpret=interpret)
+    y = rwkv6_scan.wkv6(rp, kp, vp, lp, u, chunk=chunk, interpret=True)
     return y[:, :S]
 
 
-# --- ssd ----------------------------------------------------------------------
-
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd(x, Bm, Cm, da, chunk: int = 64, interpret: bool = True):
+def ssd(x, Bm, Cm, da, chunk: int = 64, interpret: Optional[bool] = None):
     """x: (BH, S, P); Bm/Cm: (BH, S, N); da: (BH, S, 1) -> y (BH, S, P)."""
+    _require_interpret("ssd", "cumsum has no Pallas TPU lowering",
+                       interpret)
     S = x.shape[1]
     xp, _ = _pad_to(x, 1, chunk)
     bp, _ = _pad_to(Bm, 1, chunk)
     cp, _ = _pad_to(Cm, 1, chunk)
     dp, _ = _pad_to(da, 1, chunk)
-    y = ssd_mod.ssd_scan(xp, bp, cp, dp, chunk=chunk, interpret=interpret)
+    y = ssd_mod.ssd_scan(xp, bp, cp, dp, chunk=chunk, interpret=True)
     return y[:, :S]

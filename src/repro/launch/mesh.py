@@ -29,14 +29,10 @@ import jax
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """``jax.make_mesh`` across jax versions: newer releases take an
-    ``axis_types`` argument (all-Auto here, the GSPMD default); older ones
-    reject the kwarg and default to the same behaviour."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis in Auto mode (GSPMD decides the
+    layout of whatever the program does not constrain)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -128,8 +124,3 @@ def resolve_mesh(name: Optional[str]) -> Optional[jax.sharding.Mesh]:
         return make_host_mesh(n_pods)
     return make_production_mesh(multi_pod=n_pods > 1)
 
-
-# TPU v5e hardware model for the roofline analysis (per chip)
-V5E_PEAK_FLOPS = 197e12        # bf16 FLOP/s
-V5E_HBM_BW = 819e9             # bytes/s
-V5E_ICI_BW = 50e9              # bytes/s per link

@@ -117,11 +117,8 @@ def shard(x: jax.Array, *axes: Optional[str]) -> jax.Array:
     if mesh is None:
         return x
     spec = _resolve(axes, mesh, current_rules())
-    # jax >= 0.5 tracks an ambient abstract mesh inside shard_map bodies;
-    # on older versions the concrete mesh is always the right target.
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    am = get_am() if get_am is not None else None
-    if am is not None and not am.empty and set(am.axis_names) == set(
+    am = jax.sharding.get_abstract_mesh()
+    if not am.empty and set(am.axis_names) == set(
             mesh.axis_names):
         return jax.lax.with_sharding_constraint(x, NamedSharding(am, spec))
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))

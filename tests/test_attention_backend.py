@@ -45,6 +45,19 @@ def test_blocked_matches_pallas_interpret(window):
     assert float(jnp.max(jnp.abs(ob - op))) < 1e-5
 
 
+@pytest.mark.parametrize("window", [None, 16])
+def test_flash_kernel_grad_matches_blocked(window):
+    """The Pallas kernel's custom VJP (blocked-attention backward from the
+    saved inputs) gives the streaming path's gradients."""
+    q, k, v = _qkv(jax.random.PRNGKey(2))
+    loss = lambda impl: lambda *a: jnp.sum(  # noqa: E731
+        K.attention(*a, window=window, impl=impl, block=16) ** 2)
+    gp = jax.grad(loss("pallas_interpret"), argnums=(0, 1, 2))(q, k, v)
+    gb = jax.grad(loss("blocked"), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gp, gb):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-4
+
+
 def test_attention_impl_validation():
     q, k, v = _qkv(jax.random.PRNGKey(1))
     with pytest.raises(ValueError, match="unknown attention impl"):

@@ -40,8 +40,7 @@ SCRIPT = textwrap.dedent("""
                        out_shardings=(fns.state_shardings, None)
                        ).lower(state, batch).compile()
     txt = comp.as_text()
-    ca = comp.cost_analysis()           # dict on new jax, list on 0.4.x
-    ca = (ca[0] if ca else {{}}) if isinstance(ca, list) else ca
+    ca = comp.cost_analysis()
     out = {{
         "colls": count_collectives(txt),
         "coll_bytes": collective_bytes(txt),

@@ -31,8 +31,8 @@ def test_codec_kernel_matches_ref(nblocks, bits):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_codec_roundtrip_bound(dtype):
     x = (_rand(3, (2000,), jnp.float32) * 2).astype(dtype)
-    q, s = ops.compress(x, 8)
-    xr = ops.decompress(q, s, (2000,))
+    q, s = ops.compress(x, 8, interpret=True)
+    xr = ops.decompress(q, s, (2000,), interpret=True)
     tol = float(jnp.max(jnp.abs(x.astype(jnp.float32)))) / 127 * 0.51 + 0.01
     assert float(jnp.max(jnp.abs(xr - x.astype(jnp.float32)))) <= tol
 
@@ -56,7 +56,8 @@ def test_flash_attention_matches_ref(case, dtype):
     q = _rand(1, (2, S, H, hd), dtype)
     k = _rand(2, (2, T, KV, hd), dtype)
     v = _rand(3, (2, T, KV, hd), dtype)
-    out = ops.flash_attention(q, k, v, causal=causal, window=window)
+    out = ops.flash_attention(q, k, v, causal=causal, window=window,
+                              interpret=True)
     G = H // KV
     kr = jnp.repeat(k, G, 2).transpose(0, 2, 1, 3).reshape(2 * H, T, hd)
     vr = jnp.repeat(v, G, 2).transpose(0, 2, 1, 3).reshape(2 * H, T, hd)
@@ -82,7 +83,7 @@ def test_wkv6_matches_ref(case, dtype):
     v = _rand(3, (BH, S, N), dtype)
     logw = (-jnp.exp(_rand(4, (BH, S, N), jnp.float32))).astype(jnp.float32)
     u = _rand(5, (BH, N), jnp.float32)
-    y = ops.wkv6(r, k, v, logw, u, chunk=chunk)
+    y = ops.wkv6(r, k, v, logw, u, chunk=chunk, interpret=True)
     yr = ref.wkv6(r, k, v, logw, u)
     tol = 5e-4 if dtype == jnp.float32 else 5e-2
     err = float(jnp.max(jnp.abs(y.astype(jnp.float32) -
@@ -98,7 +99,7 @@ def test_wkv6_strong_decay_stable():
     v = _rand(3, (BH, S, N), jnp.float32)
     logw = jnp.full((BH, S, N), -8.0)
     u = jnp.zeros((BH, N))
-    y = ops.wkv6(r, k, v, logw, u, chunk=64)
+    y = ops.wkv6(r, k, v, logw, u, chunk=64, interpret=True)
     assert bool(jnp.all(jnp.isfinite(y)))
 
 
@@ -113,7 +114,7 @@ def test_ssd_matches_ref(case, dtype):
     Bm = _rand(2, (BH, S, N), dtype)
     Cm = _rand(3, (BH, S, N), dtype)
     da = -jnp.abs(_rand(4, (BH, S, 1), jnp.float32))
-    y = ops.ssd(x, Bm, Cm, da, chunk=chunk)
+    y = ops.ssd(x, Bm, Cm, da, chunk=chunk, interpret=True)
     yr = ref.ssd(x, Bm, Cm, da)
     tol = 5e-4 if dtype == jnp.float32 else 1e-1
     err = float(jnp.max(jnp.abs(y.astype(jnp.float32) -
@@ -139,7 +140,29 @@ def test_model_rwkv_block_matches_kernel():
     vf = v.transpose(0, 2, 1, 3).reshape(BH * H, S, N)
     lwf = lw.transpose(0, 2, 1, 3).reshape(BH * H, S, N)
     uf = jnp.tile(u, (BH, 1))
-    y_kern = ops.wkv6(rf, kf, vf, lwf, uf, chunk=32)
+    y_kern = ops.wkv6(rf, kf, vf, lwf, uf, chunk=32, interpret=True)
     y_kern = y_kern.reshape(BH, H, S, N).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(y_model), np.asarray(y_kern),
                                atol=5e-4)
+
+
+def test_interpret_mode_follows_backend():
+    """``interpret=None`` resolves from the backend: compiled Pallas on a
+    TPU, the interpreter elsewhere; an explicit bool wins."""
+    assert ops.interpret_mode(None) == (jax.default_backend() != "tpu")
+    assert ops.interpret_mode(True) is True
+    assert ops.interpret_mode(False) is False
+
+
+@pytest.mark.parametrize("kernel", ["wkv6", "ssd"])
+def test_chunked_scans_refuse_compiled_mode(kernel):
+    """The chunked-scan kernels do not compile for TPU v5e; asking for the
+    compiled kernel raises instead of silently interpreting."""
+    x = _rand(0, (2, 64, 16), jnp.float32)
+    with pytest.raises(NotImplementedError, match="does not compile"):
+        if kernel == "wkv6":
+            ops.wkv6(x, x, x, -jnp.abs(x), x[:, 0], chunk=32,
+                     interpret=False)
+        else:
+            ops.ssd(x, x, x, -jnp.abs(x[..., :1]), chunk=32,
+                    interpret=False)

@@ -13,6 +13,8 @@
   hypothesis property tests (tests/_hypothesis.py: the @given tests
   skip when hypothesis is not installed; the direct tests still run).
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -402,17 +404,20 @@ def test_prop_availability_rate_converges(p, seed):
     assert abs(pop.availability_mask(0.0).mean() - p) < 0.025
 
 
+@functools.lru_cache(maxsize=None)
+def _sampler_env():
+    return SimEnv(SimConfig(
+        population=PopulationConfig(
+            plane="stacked", availability="bernoulli:0.6:20", seed=3),
+        n_clients=256, samples_per_client=20, image_hw=8, n_tiers=4,
+        clients_per_round=8, n_unstable=16))
+
+
 @given(now=st.floats(0, 500), k=st.integers(1, 16),
        rng_seed=st.integers(0, 2**20))
 @settings(max_examples=20, deadline=None)
-def test_prop_sampler_respects_masks(now, k, rng_seed, _env_cache={}):
-    env = _env_cache.get("env")
-    if env is None:
-        env = _env_cache["env"] = SimEnv(SimConfig(
-            population=PopulationConfig(
-                plane="stacked", availability="bernoulli:0.6:20", seed=3),
-            n_clients=256, samples_per_client=20, image_hw=8, n_tiers=4,
-            clients_per_round=8, n_unstable=16))
+def test_prop_sampler_respects_masks(now, k, rng_seed):
+    env = _sampler_env()
     alive = env.alive(now)
     rng = np.random.default_rng(rng_seed)
     for m in range(env.tm.n_tiers):
