@@ -29,6 +29,7 @@ import enum
 import pickle
 from typing import Any, Optional
 
+import jax
 import numpy as np
 
 from repro.core import faults as faults_mod
@@ -264,30 +265,39 @@ def run_engine(env: SimEnv, strategy: ServerStrategy, cfg: EngineConfig,
 
     try:
         while ctx.t_global < cfg.total_updates and len(ctx.q):
-            now, actor = ctx.q.pop()
-            if ctx.faults is not None and faults_mod.is_fault_event(actor):
-                out = strategy.on_fault(env, ctx, now, actor)
-            else:
-                out = strategy.on_event(env, ctx, now, actor)
-            if out is Outcome.DISCARD:
-                continue
-            ctx.t_global += 1
-            if (out is not Outcome.SKIP_ROUND
-                    and (ctx.t_global % cfg.eval_every == 0
-                         or ctx.t_global == cfg.total_updates)):
-                acc, var = env.evaluate(strategy.global_params())
-                strategy.on_eval(env, ctx)
-                ctx.metrics.record(now, ctx.t_global, acc, var,
-                                   ctx.bytes_up, ctx.bytes_down)
-                if on_record is not None:
-                    on_record({"time": now, "round": ctx.t_global,
-                               "acc": acc, "acc_var": var,
-                               "bytes_up": ctx.bytes_up,
-                               "bytes_down": ctx.bytes_down})
-            if cfg.retier_every and ctx.t_global % cfg.retier_every == 0:
-                env.retier(ctx.rng, cfg.retier_drift)
-            if mgr is not None and ctx.t_global % every == 0:
-                mgr.save(ctx.t_global, _engine_snapshot(ctx, strategy, env))
+            # profiler spans (DESIGN.md §Tracing), no-ops unless a
+            # jax.profiler session is active; the events up to and
+            # including a committed update share its step id
+            with jax.profiler.StepTraceAnnotation("repro.event",
+                                                  step_num=ctx.t_global):
+                now, actor = ctx.q.pop()
+                with jax.profiler.TraceAnnotation("repro.strategy"):
+                    if (ctx.faults is not None
+                            and faults_mod.is_fault_event(actor)):
+                        out = strategy.on_fault(env, ctx, now, actor)
+                    else:
+                        out = strategy.on_event(env, ctx, now, actor)
+                if out is Outcome.DISCARD:
+                    continue
+                ctx.t_global += 1
+                if (out is not Outcome.SKIP_ROUND
+                        and (ctx.t_global % cfg.eval_every == 0
+                             or ctx.t_global == cfg.total_updates)):
+                    acc, var = env.evaluate(strategy.global_params())
+                    with jax.profiler.TraceAnnotation("repro.on_eval"):
+                        strategy.on_eval(env, ctx)
+                    ctx.metrics.record(now, ctx.t_global, acc, var,
+                                       ctx.bytes_up, ctx.bytes_down)
+                    if on_record is not None:
+                        on_record({"time": now, "round": ctx.t_global,
+                                   "acc": acc, "acc_var": var,
+                                   "bytes_up": ctx.bytes_up,
+                                   "bytes_down": ctx.bytes_down})
+                if cfg.retier_every and ctx.t_global % cfg.retier_every == 0:
+                    env.retier(ctx.rng, cfg.retier_drift)
+                if mgr is not None and ctx.t_global % every == 0:
+                    mgr.save(ctx.t_global,
+                             _engine_snapshot(ctx, strategy, env))
     finally:
         if mgr is not None:
             mgr.wait()

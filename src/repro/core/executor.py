@@ -201,7 +201,8 @@ class RoundExecutor:
         batch = self.env.population.materialize(pid)
         self.stream_bytes = max(self.stream_bytes,
                                 sum(a.nbytes for a in batch.values()))
-        return {k: jnp.asarray(v) for k, v in batch.items()}
+        with jax.profiler.TraceAnnotation("repro.round.h2d"):
+            return {k: jnp.asarray(v) for k, v in batch.items()}
 
     def _pad_topology(self, ids_edges):
         """Per-edge live id lists -> the flat (E*K_edge,) padded id
@@ -615,20 +616,21 @@ class RoundExecutor:
         (K,) bool uplink-poison mask over the padded client axis (None =
         no poisoning this round).
         """
-        pid, ns = self._pad_ids(ids)
-        data = self._round_data(pid)
-        keys = self._pad_keys(seed, len(ids))
-        if gate is None:
-            step = self._fedat_step(codec, use_prox)
-            return step(w_global, tier_models, np.int32(m), data,
-                        aggregation.client_weights_host(ns), cross_weights,
-                        keys)
-        step = self._fedat_step_gated(codec, use_prox, gate)
-        if poison is None:
-            poison = np.zeros(self.K, bool)
-        return step(w_global, tier_models, np.int32(m), data,
-                    aggregation.client_weights_host(ns), cross_weights,
-                    keys, poison)
+        with jax.profiler.TraceAnnotation("repro.round"):
+            pid, ns = self._pad_ids(ids)
+            data = self._round_data(pid)
+            with jax.profiler.TraceAnnotation("repro.round.keys"):
+                keys = self._pad_keys(seed, len(ids))
+            if gate is None:
+                step, extra = self._fedat_step(codec, use_prox), ()
+            else:
+                step = self._fedat_step_gated(codec, use_prox, gate)
+                extra = (np.zeros(self.K, bool) if poison is None
+                         else poison,)
+            w_intra = aggregation.client_weights_host(ns)
+            with jax.profiler.TraceAnnotation("repro.round.launch"):
+                return step(w_global, tier_models, np.int32(m), data,
+                            w_intra, cross_weights, keys, *extra)
 
     def fedat_topology_round(self, w_global, silo_models, dispatch, s: int,
                              ids_edges, seed: int, *, codecs,
@@ -654,12 +656,15 @@ class RoundExecutor:
                 f"data axis D={self.D}); use a D==1 mesh — multi-pod "
                 f"host meshes with one device per pod still map silos "
                 f"onto the pod axis (mesh.shard_tiers)")
-        pid, w_intra, w_edge, counts = self._pad_topology(ids_edges)
-        data = self._round_data(pid)
-        keys = self._pad_topology_keys(seed, counts)
-        step = self._fedat_topology_step(codecs, use_prox)
-        return step(w_global, silo_models, dispatch, np.int32(s), data,
-                    w_intra, w_edge, cross_weights, keys)
+        with jax.profiler.TraceAnnotation("repro.round"):
+            pid, w_intra, w_edge, counts = self._pad_topology(ids_edges)
+            data = self._round_data(pid)
+            with jax.profiler.TraceAnnotation("repro.round.keys"):
+                keys = self._pad_topology_keys(seed, counts)
+            step = self._fedat_topology_step(codecs, use_prox)
+            with jax.profiler.TraceAnnotation("repro.round.launch"):
+                return step(w_global, silo_models, dispatch, np.int32(s),
+                            data, w_intra, w_edge, cross_weights, keys)
 
     def fedavg_round(self, w, ids: np.ndarray, seed: int, *, codec=None,
                      gate=None, poison=None):
@@ -669,17 +674,20 @@ class RoundExecutor:
         data axis exactly like :meth:`fedat_round` (TiFL rounds run
         through here too).  ``gate``/``poison`` select the fault plane's
         gated step, as in :meth:`fedat_round`."""
-        pid, ns = self._pad_ids(ids)
-        data = self._round_data(pid)
-        keys = self._pad_keys(seed, len(ids))
-        if gate is None:
-            step = self._fedavg_step(codec)
-            return step(w, data, aggregation.client_weights_host(ns), keys)
-        step = self._fedavg_step_gated(codec, gate)
-        if poison is None:
-            poison = np.zeros(self.K, bool)
-        return step(w, data, aggregation.client_weights_host(ns), keys,
-                    poison)
+        with jax.profiler.TraceAnnotation("repro.round"):
+            pid, ns = self._pad_ids(ids)
+            data = self._round_data(pid)
+            with jax.profiler.TraceAnnotation("repro.round.keys"):
+                keys = self._pad_keys(seed, len(ids))
+            if gate is None:
+                step, extra = self._fedavg_step(codec), ()
+            else:
+                step = self._fedavg_step_gated(codec, gate)
+                extra = (np.zeros(self.K, bool) if poison is None
+                         else poison,)
+            w_intra = aggregation.client_weights_host(ns)
+            with jax.profiler.TraceAnnotation("repro.round.launch"):
+                return step(w, data, w_intra, keys, *extra)
 
     def fedasync_round(self, w, client: int, a_eff: float, seed: int, *,
                        codec=None):
@@ -688,8 +696,11 @@ class RoundExecutor:
         The interpolation coefficients are rounded to f32 host-side so the
         in-graph math matches the seed loop's eager ``(1-a)*g + a*l``.
         """
-        step = self._fedasync_step(codec)
-        keys = jax.random.split(jax.random.PRNGKey(seed), 1)
-        data = self._round_data(np.asarray([client], np.int32))
-        return step(w, data, np.float32(1.0 - a_eff), np.float32(a_eff),
-                    keys)
+        with jax.profiler.TraceAnnotation("repro.round"):
+            step = self._fedasync_step(codec)
+            with jax.profiler.TraceAnnotation("repro.round.keys"):
+                keys = jax.random.split(jax.random.PRNGKey(seed), 1)
+            data = self._round_data(np.asarray([client], np.int32))
+            with jax.profiler.TraceAnnotation("repro.round.launch"):
+                return step(w, data, np.float32(1.0 - a_eff),
+                            np.float32(a_eff), keys)

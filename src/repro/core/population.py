@@ -54,6 +54,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.data.federated import _class_templates, parse_partitioner
@@ -382,18 +383,20 @@ class Population:
         fixed ``(len(ids), cap_train, ...)`` shape.  Duplicate ids (the
         executor's dead-slot padding repeats a live id) are generated
         once and copied, so a padded round costs the live clients only."""
-        ids = np.asarray(ids)
-        k = len(ids)
-        xs = np.zeros((k, self.cap_train) + self.shape, self.dtype)
-        ys = np.zeros((k, self.cap_train), np.int32)
-        mask = np.zeros((k, self.cap_train), bool)
-        rows = {int(c): self.client_rows(int(c)) for c in np.unique(ids)}
-        for j, c in enumerate(ids):
-            x, y, n_tr = rows[int(c)]
-            xs[j, :n_tr] = x[:n_tr]
-            ys[j, :n_tr] = y[:n_tr]
-            mask[j, :n_tr] = True
-        return {"x": xs, "y": ys, "mask": mask}
+        with jax.profiler.TraceAnnotation("repro.materialize"):
+            ids = np.asarray(ids)
+            k = len(ids)
+            xs = np.zeros((k, self.cap_train) + self.shape, self.dtype)
+            ys = np.zeros((k, self.cap_train), np.int32)
+            mask = np.zeros((k, self.cap_train), bool)
+            rows = {int(c): self.client_rows(int(c))
+                    for c in np.unique(ids)}
+            for j, c in enumerate(ids):
+                x, y, n_tr = rows[int(c)]
+                xs[j, :n_tr] = x[:n_tr]
+                ys[j, :n_tr] = y[:n_tr]
+                mask[j, :n_tr] = True
+            return {"x": xs, "y": ys, "mask": mask}
 
     def materialize_stack(self) -> Dict[str, np.ndarray]:
         """The full resident train stack (the ``stacked`` plane): the same

@@ -323,16 +323,17 @@ class SimEnv:
         the participant set (Eq. 4 renormalizes over survivors).  With a
         population availability process the slotted Bernoulli mask is
         folded in too (core/population.py)."""
-        up = self.dropout_at > now
-        if self.churn_down is not None:
-            starts, ends = self.churn_down
-            down = ((starts <= now) & (now < ends)).any(axis=1)
-            up = up & ~down
-        if self.population is not None:
-            avail = self.population.availability_mask(now)
-            if avail is not None:
-                up = up & avail
-        return up
+        with jax.profiler.TraceAnnotation("repro.alive"):
+            up = self.dropout_at > now
+            if self.churn_down is not None:
+                starts, ends = self.churn_down
+                down = ((starts <= now) & (now < ends)).any(axis=1)
+                up = up & ~down
+            if self.population is not None:
+                avail = self.population.availability_mask(now)
+                if avail is not None:
+                    up = up & avail
+            return up
 
     def completion(self, now: float) -> Optional[np.ndarray]:
         """Per-client round-completion mask at ``now`` under the
@@ -390,11 +391,18 @@ class SimEnv:
         return test + peak
 
     def evaluate(self, params) -> Tuple[float, float]:
-        """(weighted global accuracy, per-client accuracy variance)."""
-        if self._test_dev is None:  # upload the test stack once
-            self._test_dev = tuple(jnp.asarray(self.test[k])
-                                   for k in ("x", "y", "mask"))
-        accs = np.asarray(self.eval_fn(params, *self._test_dev))
-        weights = self.test["mask"].sum(1)
-        glob = float((accs * weights).sum() / weights.sum())
-        return glob, float(np.var(accs))
+        """(weighted global accuracy, per-client accuracy variance).
+
+        The eval program queues behind every round step still running on
+        the device; ``repro.eval.wait`` makes that wait explicit, so a
+        profiler trace shows it apart from the eval itself."""
+        with jax.profiler.TraceAnnotation("repro.eval"):
+            if self._test_dev is None:  # upload the test stack once
+                self._test_dev = tuple(jnp.asarray(self.test[k])
+                                       for k in ("x", "y", "mask"))
+            with jax.profiler.TraceAnnotation("repro.eval.wait"):
+                jax.block_until_ready(params)
+            accs = np.asarray(self.eval_fn(params, *self._test_dev))
+            weights = self.test["mask"].sum(1)
+            glob = float((accs * weights).sum() / weights.sum())
+            return glob, float(np.var(accs))
