@@ -133,9 +133,9 @@ def _has_kernel(ex, key, strategy):
     donated, and the trace cache is hit (no retrace)."""
     step = ex._steps[key]
     K, M = ex.K, len(strategy.counts)
-    args = (strategy.w_global, strategy.tier_models, np.int32(0),
-            np.zeros(K, np.int32), np.zeros(K, np.float32),
-            np.zeros(M, np.float32), ex._pad_keys(0, K))
+    args = (strategy.w_global, strategy.tier_models,
+            np.array([0, 0, K], np.int32), np.zeros(K, np.int32),
+            np.zeros(K, np.float32), np.zeros(M, np.float32))
     return "tpu_custom_call" in step.lower(*args).compile().as_text()
 
 
@@ -169,7 +169,7 @@ def _round_inputs(env, seed=7):
         "w": w0, "tiers": jax.tree.map(lambda l: np.stack([l] * M), w0),
         "ids": ids, "pid": pid, "w_intra": aggregation.client_weights_host(ns),
         "cw": aggregation.uniform_weights_host(M),
-        "keys": np.asarray(ex._pad_keys(seed, len(ids))), "seed": seed}
+        "ints": np.array([0, seed, len(ids)], np.int32), "seed": seed}
 
 
 def phase_a(c: Checks):
@@ -204,9 +204,8 @@ def phase_a(c: Checks):
     def on(dev, precision="default"):
         put = lambda t: jax.device_put(t, dev)  # noqa: E731
         with jax.default_matmul_precision(precision):
-            w, _ = step(put(inp["w"]), put(inp["tiers"]), put(np.int32(0)),
-                        put(data), put(inp["w_intra"]), put(inp["cw"]),
-                        put(inp["keys"]))
+            w, _ = step(put(inp["w"]), put(inp["tiers"]), put(inp["ints"]),
+                        put(data), put(inp["w_intra"]), put(inp["cw"]))
         return jax.tree.map(np.asarray, w)
 
     cpu = on(jax.devices("cpu")[0])

@@ -38,10 +38,17 @@ an optimization barrier; the model-sized math (train, codec, averages,
 tier-slot scatter) all stays in-graph.
 
 RNG parity: the seed loops draw ``rng.integers(2**31)`` per event and
-``jax.random.split`` to the *live* client count.  ``split(key, K)`` is not
-prefix-stable in ``split(key, n)``, so the executor splits host-side to
-``n`` and pads the key array to ``K`` rows — padded slots train on garbage
-keys but carry zero weight.
+``jax.random.split`` to the *live* client count.  Under the partitionable
+threefry (``jax_threefry_partitionable``, JAX's default) splits are
+prefix-stable: ``split(key, K)[:n] == split(key, n)`` bit for bit.  So
+each step takes the event's ``seed`` and live count ``n_live`` as int32
+data and derives the padded (K, 2) key array in the graph
+(:func:`_round_keys`): the K-way split, with rows ``>= n_live`` zeroed.
+Padded slots train on zero keys but carry zero weight.  The live count
+is traced, so it never retraces the step.  The round's integers (FedAT's
+tier slot ``m``, ``seed``, ``n_live``) travel as one int32 vector: each
+host array passed to a jitted call is a host-to-device copy of its own
+(0.1–0.35 ms on a TPU v5e host).
 
 Trace accounting: every fused step bumps ``trace_counts[step_key]`` at
 trace time (a Python side effect inside the jitted function body), which
@@ -81,6 +88,18 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import aggregation
 from repro.runtime import sharding as shd
+
+
+def _round_keys(seed, n_live, K: int) -> jax.Array:
+    """The round's (K, 2) client keys, derived inside a fused step:
+    ``split(PRNGKey(seed), K)`` with the rows of dead slots
+    (``>= n_live``) zeroed — bitwise ``RoundExecutor._pad_keys(seed,
+    n_live)``, since the split is prefix-stable."""
+    assert jax.config.jax_threefry_partitionable, (
+        "in-graph round keys need jax_threefry_partitionable: only then "
+        "is split(key, K)[:n] == split(key, n), the seed loops' keys")
+    keys = jax.random.split(jax.random.PRNGKey(seed), K)
+    return jnp.where(jnp.arange(K)[:, None] < n_live, keys, 0)
 
 
 def _pin(tree: Any) -> Any:
@@ -169,7 +188,8 @@ class RoundExecutor:
 
     def _pad_keys(self, seed: int, n: int) -> jax.Array:
         """Split to the live count (rng parity with the seed loops), then
-        pad to K rows; padded rows are zero keys behind zero weights."""
+        pad to K rows; padded rows are zero keys behind zero weights.
+        The host-side twin of the steps' in-graph :func:`_round_keys`."""
         keys = jax.random.split(jax.random.PRNGKey(seed), n)
         if n == self.K:
             return keys
@@ -324,8 +344,9 @@ class RoundExecutor:
         update = env.update_fn_raw if use_prox else env.update_fn_noprox_raw
         train = self._train_psum(update, codec.lossy)
 
-        def step(w_global, tier_models, m, data, w_intra, w_cross, keys):
+        def step(w_global, tier_models, ints, data, w_intra, w_cross):
             self._bump(key)
+            m, keys = ints[0], _round_keys(ints[1], ints[2], self.K)
             tier_model = _pin(
                 train(w_global, self._select(data), keys, w_intra))
             tier_models = self._tier_place(jax.tree.map(
@@ -346,8 +367,9 @@ class RoundExecutor:
         train = self._train_psum(update, None if codec is None
                                  else codec.lossy)
 
-        def step(w, data, w_intra, keys):
+        def step(w, data, w_intra, ints):
             self._bump(key)
+            keys = _round_keys(ints[0], ints[1], self.K)
             return train(w, self._select(data), keys, w_intra)
 
         self._steps[key] = jax.jit(step, donate_argnums=(0,))
@@ -365,8 +387,9 @@ class RoundExecutor:
         update = env.update_fn_raw if use_prox else env.update_fn_noprox_raw
         lossy = codec.lossy
 
-        def step(w_global, tier_models, m, data, w_intra, w_cross, keys):
+        def step(w_global, tier_models, ints, data, w_intra, w_cross):
             self._bump(key)
+            m, keys = ints[0], _round_keys(ints[1], ints[2], self.K)
             w_sent = _pin(lossy(w_global))
             client_params, _ = update(w_sent, self._select(data), keys)
             client_params = _pin(lossy(_pin(client_params)))
@@ -479,9 +502,10 @@ class RoundExecutor:
         lossy = codec.lossy
         clip = float(gate.clip_norm)
 
-        def step(w_global, tier_models, m, data, w_intra, w_cross, keys,
+        def step(w_global, tier_models, ints, data, w_intra, w_cross,
                  poison):
             self._bump(key)
+            m, keys = ints[0], _round_keys(ints[1], ints[2], self.K)
             w_sent = _pin(lossy(w_global))
             client_params, _ = update(w_sent, self._select(data), keys)
             client_params = _pin(lossy(_pin(client_params)))
@@ -514,8 +538,9 @@ class RoundExecutor:
             return self._steps[key]
         update = self.env.update_fn_noprox_raw
 
-        def step(w, data, w_intra, keys):
+        def step(w, data, w_intra, ints):
             self._bump(key)
+            keys = _round_keys(ints[0], ints[1], self.K)
             w_in = w if codec is None else _pin(codec.lossy(w))
             client_params, _ = update(w_in, self._select(data), keys)
             if codec is not None:
@@ -542,8 +567,9 @@ class RoundExecutor:
         update = self.env.update_fn_noprox_raw
         clip = float(gate.clip_norm)
 
-        def step(w, data, w_intra, keys, poison):
+        def step(w, data, w_intra, ints, poison):
             self._bump(key)
+            keys = _round_keys(ints[0], ints[1], self.K)
             w_in = w if codec is None else _pin(codec.lossy(w))
             client_params, _ = update(w_in, self._select(data), keys)
             if codec is not None:
@@ -570,8 +596,9 @@ class RoundExecutor:
             return self._steps[key]
         update = self.env.update_fn_noprox_raw
 
-        def step(w, data, c_glob, c_loc, keys):
+        def step(w, data, c_glob, c_loc, seed):
             self._bump(key)
+            keys = _round_keys(seed, 1, 1)
             w_in = w if codec is None else _pin(codec.lossy(w))
             client_params, _ = update(w_in, self._select(data), keys)
             client_w = _pin(jax.tree.map(lambda a: a[0], client_params))
@@ -620,7 +647,7 @@ class RoundExecutor:
             pid, ns = self._pad_ids(ids)
             data = self._round_data(pid)
             with jax.profiler.TraceAnnotation("repro.round.keys"):
-                keys = self._pad_keys(seed, len(ids))
+                ints = np.array([m, seed, len(ids)], np.int32)
             if gate is None:
                 step, extra = self._fedat_step(codec, use_prox), ()
             else:
@@ -629,8 +656,8 @@ class RoundExecutor:
                          else poison,)
             w_intra = aggregation.client_weights_host(ns)
             with jax.profiler.TraceAnnotation("repro.round.launch"):
-                return step(w_global, tier_models, np.int32(m), data,
-                            w_intra, cross_weights, keys, *extra)
+                return step(w_global, tier_models, ints, data, w_intra,
+                            cross_weights, *extra)
 
     def fedat_topology_round(self, w_global, silo_models, dispatch, s: int,
                              ids_edges, seed: int, *, codecs,
@@ -678,7 +705,7 @@ class RoundExecutor:
             pid, ns = self._pad_ids(ids)
             data = self._round_data(pid)
             with jax.profiler.TraceAnnotation("repro.round.keys"):
-                keys = self._pad_keys(seed, len(ids))
+                ints = np.array([seed, len(ids)], np.int32)
             if gate is None:
                 step, extra = self._fedavg_step(codec), ()
             else:
@@ -687,7 +714,7 @@ class RoundExecutor:
                          else poison,)
             w_intra = aggregation.client_weights_host(ns)
             with jax.profiler.TraceAnnotation("repro.round.launch"):
-                return step(w, data, w_intra, keys, *extra)
+                return step(w, data, w_intra, ints, *extra)
 
     def fedasync_round(self, w, client: int, a_eff: float, seed: int, *,
                        codec=None):
@@ -699,8 +726,8 @@ class RoundExecutor:
         with jax.profiler.TraceAnnotation("repro.round"):
             step = self._fedasync_step(codec)
             with jax.profiler.TraceAnnotation("repro.round.keys"):
-                keys = jax.random.split(jax.random.PRNGKey(seed), 1)
+                key = np.int32(seed)
             data = self._round_data(np.asarray([client], np.int32))
             with jax.profiler.TraceAnnotation("repro.round.launch"):
                 return step(w, data, np.float32(1.0 - a_eff),
-                            np.float32(a_eff), keys)
+                            np.float32(a_eff), key)

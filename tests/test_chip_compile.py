@@ -106,9 +106,8 @@ def test_fused_cnn_round_compiles_for_v5e(one_chip):
                      env.params0)
     tiers = jax.tree.map(lambda l: _spec(one_chip, (M,) + l.shape, l.dtype),
                          env.params0)
-    c = step.lower(w, tiers, _spec(one_chip, (), jnp.int32), data,
-                   _spec(one_chip, (K,)), _spec(one_chip, (M,)),
-                   _spec(one_chip, (K, 2), jnp.uint32)).compile()
+    c = step.lower(w, tiers, _spec(one_chip, (3,), jnp.int32), data,
+                   _spec(one_chip, (K,)), _spec(one_chip, (M,))).compile()
     assert "tpu_custom_call" in c.as_text()
     mem = c.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9

@@ -15,15 +15,18 @@ from repro.compress import transport
 from repro.core import aggregation
 from repro.core.baselines import BaselineConfig, run_fedasync, run_fedavg, \
     run_tifl
+from repro.core.executor import _round_keys
 from repro.core.fedat import FedATConfig, fake_polyline, run_fedat
 from repro.core.simulation import SimConfig, SimEnv
+
+K = 4                                   # the env's clients_per_round
 
 
 @pytest.fixture(scope="module")
 def env():
     return SimEnv(SimConfig(n_clients=12, n_tiers=3, samples_per_client=20,
                             classes_per_client=2, image_hw=8,
-                            clients_per_round=4, local_epochs=1,
+                            clients_per_round=K, local_epochs=1,
                             n_unstable=2))
 
 
@@ -110,6 +113,27 @@ def test_padded_round_matches_eager_reference_bitwise(env):
         m, ids, seed, codec=codec, use_prox=True, cross_weights=cw)
     assert _bitwise_equal(stack_ref, stack)
     assert _bitwise_equal(wg_ref, wg)
+
+
+@pytest.mark.parametrize("seed", [20260801, 2 ** 31 - 1])
+@pytest.mark.parametrize("n", range(1, K + 1))
+def test_in_graph_round_keys_match_host_padding_bitwise(env, n, seed):
+    """The steps' in-graph keys (one K-way split, dead rows zeroed) are
+    the host-side split to the live count padded with zero rows, bit for
+    bit: the partitionable threefry's split is prefix-stable."""
+    ex = env.executor()
+    keys = jax.jit(_round_keys, static_argnums=2)(np.int32(seed),
+                                                  np.int32(n), ex.K)
+    ref = np.asarray(ex._pad_keys(seed, n))
+    assert keys.dtype == ref.dtype and keys.shape == ref.shape == (K, 2)
+    assert (np.asarray(keys) == ref).all()
+
+
+@pytest.mark.parametrize("seed", [20260801, 2 ** 31 - 1])
+def test_fedasync_in_graph_key_matches_split(seed):
+    key = jax.jit(lambda s: _round_keys(s, 1, 1))(np.int32(seed))
+    ref = np.asarray(jax.random.split(jax.random.PRNGKey(seed), 1))
+    assert key.dtype == ref.dtype and (np.asarray(key) == ref).all()
 
 
 def test_zero_weight_slots_are_bitwise_neutral():
