@@ -59,7 +59,7 @@ def planted(m, kind: str, detail: bool = False):
     if kind == "unchanged":
         start = run.reference(m)
         out = run.readings(m, start.state(),
-                           [dict(start.w_global) for _ in rec.steps])
+                           [start.w_global for _ in rec.steps])
     else:
         other = {"bf16": lambda: run.reference(m, jnp.bfloat16, None),
                  "high": lambda: run.reference(m, precision=HIGH),

@@ -9,13 +9,16 @@ the program, or what stands in its place, through the same set-up steps.
   median leaf's, worst leaf.  A norm gap and not the norm of the
   difference: local Adam turns rounding into sign flips of small
   gradients, so element values drift while the size of the update stays.
-  Leaves that the reference leaves all but unmoved (change under a
-  thousandth of the median leaf's, as the global model is before Eq. 3
-  has weight on an updated tier) are left out.
+  The median leaf is the median of the leaves the reference moves.  A
+  leaf the reference holds exactly still (as the global model is before
+  Eq. 3 has weight on an updated tier) is held to the median leaf: what
+  the program moves there is a gap.  Leaves the reference moves by
+  round-off alone (a change under a thousandth of the median leaf's)
+  are left out.
 * ``loss_gap``: for each of those updates, the tier model it wrote,
-  scored by the reference's cross-entropy over the live training rows of
-  that update's clients; the largest gap to the reference's own tier
-  model, relative.
+  scored by the reference's mean loss (the model's ``metrics``) over
+  the live training rows of that update's clients; the largest gap to
+  the reference's own tier model, relative.
 * ``change_gap_med``, ``step_gap``, ``step_gap_med``, ``first_loss_gap``:
   the same gaps by the median leaf, per update's written tier model
   (change from the initial weights, worst step), and for the first
@@ -28,14 +31,18 @@ from typing import Dict
 
 import numpy as np
 
+from bench.pytree import named
+
 #: leaves whose reference change is under this share of the median
 #: leaf's change are not compared (they move by round-off alone)
 STILL_LEAF = 1e-3
 
 
-def change_norms(start: Dict, prog: Dict, ref: Dict) -> Dict[str, list]:
-    """Per leaf, [program's, reference's] norm of the change from
-    ``start``."""
+def change_norms(start, prog, ref) -> Dict[str, list]:
+    """Per leaf name (``bench/pytree.py``), [program's, reference's]
+    norm of the change from ``start``; each of the three is a pytree or
+    a dict of named leaves."""
+    start, prog, ref = named(start), named(prog), named(ref)
     return {k: [float(np.linalg.norm(np.asarray(prog[k], np.float64)
                                      - start[k])),
                 float(np.linalg.norm(np.asarray(ref[k], np.float64)
@@ -45,10 +52,14 @@ def change_norms(start: Dict, prog: Dict, ref: Dict) -> Dict[str, list]:
 def leaf_gaps(norms: Dict[str, list]) -> Dict[str, float]:
     """Per leaf, the gap between the program's and the reference's norm
     of the change, over the larger of the reference's and the median
-    leaf's; still leaves left out."""
-    med = float(np.median([r for _, r in norms.values()]))
+    leaf's.  The median is taken over the leaves the reference moves;
+    a leaf it holds exactly still is held to the median leaf, and one it
+    moves by round-off alone (under STILL_LEAF of the median) is left
+    out."""
+    moved = [r for _, r in norms.values() if r > 0]
+    med = float(np.median(moved)) if moved else 0.0
     return {k: abs(p - r) / max(r, med) for k, (p, r) in norms.items()
-            if r >= STILL_LEAF * med and r > 0}
+            if (r == 0 and med > 0) or r >= STILL_LEAF * med > 0}
 
 
 def _worst(gaps: Dict[str, float]) -> float:

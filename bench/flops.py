@@ -1,10 +1,10 @@
 """Floating-point operations of layers, counted from their shapes.
 
-A multiply-add counts as two operations.  Bias adds, activations and
-pooling are left out: they are a fraction of a percent of a convolution
-and run on the vector unit, not against the matrix peak.  Training is
-counted as three forward passes (forward, and the two products of the
-backward pass).
+A multiply-add counts as two operations.  Bias adds, activations, norms,
+softmax and pooling are left out: they are a fraction of a percent of a
+convolution or a matmul and run on the vector unit, not against the
+matrix peak.  Training is counted as three forward passes (forward, and
+the two products of the backward pass).
 """
 from __future__ import annotations
 
@@ -21,3 +21,9 @@ def dense(n_in: int, n_out: int) -> int:
 def training(forward: int) -> int:
     """Operations of one training sample: forward plus backward."""
     return 3 * forward
+
+
+def causal_attention(seq: int, heads: int, head_dim: int) -> int:
+    """Scores and weighted values of causal self-attention over ``seq``
+    positions: each query attends itself and the positions before it."""
+    return 2 * 2 * heads * head_dim * seq * (seq + 1) // 2

@@ -16,18 +16,24 @@ environment, the benchmark's own initial weights (one jitted call from
 the seed, handed to the run as ``Run.initial_params``), warm-up of the
 eval program and of the per-round key shapes, and the first three
 committed updates, which compile the fused step and are the steps the
-reference follows.  The window then runs the same engine for
-``--seconds``: it ends after the first committed update past that time,
-with the server state drained to the device.  ``--trace 1`` profiles the
-window and reports the per-layer metrics instead of the end-to-end ones.
+reference follows.  Of those it copies to the host only the tier slot
+each one wrote, and the whole server state after the last.  The window
+then runs the same engine for ``--seconds``: it ends after the first
+committed update past that time, with the server state drained to the
+device.  ``--trace 1`` profiles the window and reports the per-layer
+metrics instead of the end-to-end ones.
 
 After the window the reference (``bench/fedat_ref.py``) recomputes the
 first three updates, and ``correct`` says whether each compared number
-is within its limit.  The
-last stdout line is the result; the compared numbers, each with its
-limit, are also the last lines on stderr.  Without an accelerator, or
-with fewer chips than the cell asks for, the run exits 2 and prints no
-result.
+is within its limit.  The model's file (``bench/models/<data.model>.py``)
+gives the reference its weights, forward pass and, where it defines
+them, its own objective and scoring (``loss``, ``metrics``) and its
+placement: a ``place(tree, mesh)`` puts the benchmark's weights and the
+reference's state on a mesh over the cell's chips (axis ``chips``);
+without one all of it lives on one device.  The last stdout line is the
+result; the compared numbers, each with its limit, are also the last
+lines on stderr.  Without an accelerator, or with fewer chips than the
+cell asks for, the run exits 2 and prints no result.
 """
 from __future__ import annotations
 
@@ -37,6 +43,7 @@ T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
+import functools  # noqa: E402
 import importlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -57,6 +64,7 @@ for _p in (ROOT, os.path.join(ROOT, "src")):
 from bench import compare, flops, peaks, trace_reduce  # noqa: E402
 from bench.fedat_ref import (HIGHEST, FedATReference,  # noqa: E402
                              accuracy_and_loss)
+from bench.pytree import named  # noqa: E402
 
 #: committed updates in set-up that the reference follows
 CHECK_STEPS = 3
@@ -143,6 +151,8 @@ class Recorder:
     def __init__(self, trace: bool):
         self.trace = trace
         self.steps = []
+        #: the server state after the last set-up step (named leaves)
+        self.final = None
         self.t0 = None
         self.t_end = None
         self.update_times = []
@@ -185,11 +195,37 @@ def wrapped(obj, attr: str, make):
             delattr(obj, attr)
 
 
+def _host(tree):
+    """``tree`` with every leaf a host array."""
+    import jax
+    return jax.tree.map(np.asarray, jax.device_get(tree))
+
+
 def _state(out) -> dict:
-    w_global, tiers = out
-    s = {f"global/{k}": np.asarray(v) for k, v in w_global.items()}
-    s.update({f"tiers/{k}": np.asarray(v) for k, v in tiers.items()})
-    return s
+    """The server state ``(w_global, tiers)`` as host arrays named by
+    leaf: ``global/<leaf>``, ``tiers/<leaf>``."""
+    w_global, tiers = _host(out)
+    return named({"global": w_global, "tiers": tiers})
+
+
+@functools.cache
+def _slot_fn():
+    """A jitted ``(tiers, m) -> tier m`` (one program for every m)."""
+    import jax
+    return jax.jit(lambda tiers, m: jax.tree.map(
+        lambda t: jax.lax.dynamic_index_in_dim(t, m, keepdims=False),
+        tiers))
+
+
+def placement(model, chips: int):
+    """The model file's ``place`` over a mesh of the cell's ``chips``
+    (axis ``chips``), or None where the file defines none."""
+    if not hasattr(model, "place"):
+        return None
+    import jax
+    from jax.sharding import Mesh
+    mesh = Mesh(np.array(jax.devices()[:chips]), ("chips",))
+    return functools.partial(model.place, mesh=mesh)
 
 
 def _rows(env, ids):
@@ -243,8 +279,11 @@ def measure(name: str, seed: int, seconds: float, trace: bool,
     marks["built"] = time.perf_counter()
     env, strategy = run.env, run.strategy
     ex = env.executor()
+    place = placement(model, chips)
     init = jax.jit(lambda k: model.init(k, flat))(
         jax.random.PRNGKey(weight_seed))
+    if place is not None:
+        init = place(init)
     run.initial_params = init
     env.evaluate(init)
     for n in range(1, ex.K + 1):        # every live-count's key shapes
@@ -253,6 +292,7 @@ def measure(name: str, seed: int, seconds: float, trace: bool,
     marks["warmed"] = time.perf_counter()
 
     rec = Recorder(trace)
+    slot = _slot_fn()
     log_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
     window_note = None
 
@@ -261,10 +301,14 @@ def measure(name: str, seed: int, seconds: float, trace: bool,
             with rec.span("dispatch"):
                 out = orig(w_global, tier_models, m, ids, seed_, **kw)
             if rec.t0 is None:
+                # to the host: the tier slot this step wrote, and the
+                # whole server state after the last set-up step only
                 marks.setdefault("first_step", time.perf_counter())
                 rec.steps.append({"m": int(m), "ids": np.array(ids),
                                   "seed": int(seed_),
-                                  "out": _state(jax.device_get(out))})
+                                  "tier": _host(slot(out[1], int(m)))})
+                if len(rec.steps) == CHECK_STEPS:
+                    rec.final = _state(out)
             else:
                 rec.live_rows.append(int(env.n_train_all[ids].sum()))
             return out
@@ -357,8 +401,7 @@ def measure(name: str, seed: int, seconds: float, trace: bool,
     n_updates = len(rec.update_times)
     window_s = rec.t_end - rec.t0
     gaps = np.diff([rec.t0] + rec.update_times)
-    final = jax.device_get(strategy.w_global)
-    leaves = [np.asarray(final[k]) for k in sorted(final)]
+    leaves = list(named(_host(strategy.w_global)).values())
     end_to_end = {
         "setup_s": rec.t0 - t_process,
         "updates_per_s": n_updates / window_s,
@@ -371,6 +414,7 @@ def measure(name: str, seed: int, seconds: float, trace: bool,
     return types.SimpleNamespace(
         cell=cell, seed=seed, trace=trace, require_tpu=require_tpu,
         chips=chips, device=device, env=env, ex=ex, rec=rec, init=init,
+        place=place,
         flat=flat, model=model, codec=codec, codec_arg=codec_arg,
         traces0=traces0, log_dir=log_dir, leaves=leaves,
         end_to_end=end_to_end,
@@ -434,22 +478,14 @@ def report(m: types.SimpleNamespace) -> dict:
 
 def reference(m, dtype=None, precision=HIGHEST) -> FedATReference:
     """A fresh reference server from the run's initial weights."""
-    import functools
-    import jax
     import jax.numpy as jnp
-    init = {k: np.asarray(v) for k, v in jax.device_get(m.init).items()}
     hp = {"epochs": m.flat["engine.local_epochs"],
           "batch": m.flat["engine.batch_size"], "lr": m.flat["engine.lr"],
           "lam": m.flat["engine.prox_lambda"]}
     lossy = functools.partial(m.codec.lossy, arg=m.codec_arg)
-    return FedATReference(init, m.flat["tiers.n_tiers"], m.model.apply,
-                          lossy, hp, dtype or jnp.float32, precision)
-
-
-def tier_written(step) -> dict:
-    """The tier model the program wrote in a set-up step."""
-    return {k.split("/", 1)[1]: v[step["m"]] for k, v in step["out"].items()
-            if k.startswith("tiers/")}
+    return FedATReference(_host(m.init), m.flat["tiers.n_tiers"],
+                          m.model, lossy, hp, dtype or jnp.float32,
+                          precision, m.place)
 
 
 def follow(m, ref: FedATReference, half: bool = False) -> list:
@@ -462,7 +498,7 @@ def follow(m, ref: FedATReference, half: bool = False) -> list:
         if half:
             ids = ids[:(len(ids) + 1) // 2]
         ref.round(step["m"], ids, step["seed"], _rows(m.env, ids))
-        tiers.append(dict(ref.tiers[step["m"]]))
+        tiers.append(ref.tiers[step["m"]])
     return tiers
 
 
@@ -471,14 +507,14 @@ def readings(m, state=None, tiers=None) -> dict:
     the set-up steps from the same weights and inputs; by default the
     program's results stand against it, or the server state after the
     last step and the tier model of each step given (a control or a
-    fault in the program's place).  Per step: each tier model's
-    cross-entropy over the live training rows of the step's clients, and
-    its change from the initial weights, leaf by leaf (norms)."""
-    apply = m.model.apply
+    fault in the program's place).  Per step: each tier model's mean
+    loss (the model's ``metrics``) over the live training rows of the
+    step's clients, and its change from the initial weights, leaf by
+    leaf (norms)."""
     ref = reference(m)
     start = ref.state()
     ref_tiers = follow(m, ref)
-    mine_tiers = ([tier_written(s) for s in m.rec.steps]
+    mine_tiers = ([s["tier"] for s in m.rec.steps]
                   if tiers is None else tiers)
     w0 = {k.split("/", 1)[1]: v for k, v in start.items()
           if k.startswith("global/")}
@@ -486,10 +522,10 @@ def readings(m, state=None, tiers=None) -> dict:
     for step, mine, theirs in zip(m.rec.steps, mine_tiers, ref_tiers):
         rows = _rows(m.env, step["ids"])
         steps.append({
-            "loss": [accuracy_and_loss(mine, rows, apply)[1],
-                     accuracy_and_loss(theirs, rows, apply)[1]],
+            "loss": [accuracy_and_loss(mine, rows, m.model)[1],
+                     accuracy_and_loss(theirs, rows, m.model)[1]],
             "leaves": compare.change_norms(w0, mine, theirs)})
-    final = m.rec.steps[-1]["out"] if state is None else state
+    final = m.rec.final if state is None else state
     return {"steps": steps,
             "final": compare.change_norms(start, final, ref.state())}
 

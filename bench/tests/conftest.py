@@ -3,7 +3,8 @@ files: the real logreg_sent140 cell at a size the CPU runs in seconds,
 held to its real limits, and a resident-data CNN cell of the tests' own
 (the paper CNN at 8x8 inputs, limits set from CPU readings: the program
 agrees with the reference to ~1e-5 there, the bfloat16 control departs
-by 0.5 in change_gap)."""
+by 0.5 in change_gap), and a tiny language model cell of the tests' own
+(nested parameters, a next-token objective)."""
 from __future__ import annotations
 
 import json
@@ -32,6 +33,23 @@ CNN = {
              "engine.eval_every": 2, "transport.codec": "polyline:4"},
     "reduced": [],
     "limits": {"change_gap": 0.05, "loss_gap": 0.003}}
+#: a resident-data tiny language model cell that exists only in the
+#: tests: nested parameters, a next-token objective.  Limits from CPU
+#: readings over 12 seeds: sound runs read change_gap up to 6.7e-5 and
+#: loss_gap up to 8.1e-8; the bfloat16 control reads at least 0.61 and
+#: 2.6e-5, half the batch 0.41 and 3.1e-4, an unchanged state 1.0 and
+#: 4.7e-3
+TINY_LM = {
+    "name": "small_tiny_lm", "source": "test",
+    "spec": {"strategy.name": "fedat", "data.model": "tiny_lm",
+             "data.vocab_size": 64, "data.seq_len": 16,
+             "data.n_classes": 10, "data.n_clients": 12,
+             "data.classes_per_client": 2, "data.samples_per_client": 20,
+             "tiers.n_tiers": 3, "tiers.clients_per_round": 4,
+             "tiers.n_unstable": 2, "engine.local_epochs": 1,
+             "engine.eval_every": 2, "transport.codec": "polyline:4"},
+    "reduced": [],
+    "limits": {"change_gap": 0.01, "loss_gap": 2e-6}}
 
 
 def _load(*parts):
@@ -40,7 +58,8 @@ def _load(*parts):
 
 
 def small_cells():
-    return ["small_" + c for c in SMALL] + ["small_cnn.resident"]
+    return (["small_" + c for c in SMALL]
+            + ["small_cnn.resident", "small_tiny_lm.resident"])
 
 
 @pytest.fixture(scope="session")
@@ -74,10 +93,11 @@ def small_root(tmp_path_factory):
         if "population.eval_clients" in wl["traffic"]:
             wl["traffic"]["population.eval_clients"] = 100
         add("small_" + cell, config, cfg, wl, entries[cell])
-    add("small_cnn.resident", "small_cnn", CNN,
-        {"config": "small_cnn", "chips": 1,
-         "traffic": {"engine.total_updates": 10 ** 9}, "why": "test"},
-        {"traffic": "resident", "chips": 1, "why": "test"})
+    for cfg in (CNN, TINY_LM):
+        add(cfg["name"] + ".resident", cfg["name"], cfg,
+            {"config": cfg["name"], "chips": 1,
+             "traffic": {"engine.total_updates": 10 ** 9}, "why": "test"},
+            {"traffic": "resident", "chips": 1, "why": "test"})
     names = {w["name"] for w in workloads}
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m:
