@@ -58,6 +58,7 @@ import numpy as np  # noqa: E402
 from repro import api, serve  # noqa: E402
 from repro.compress import transport  # noqa: E402
 from repro.core import aggregation  # noqa: E402
+from repro.core.executor import pack_round  # noqa: E402
 from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
 
 LABEL = "smoke run, not a benchmark"
@@ -131,11 +132,13 @@ def _has_kernel(ex, key, strategy):
     """Whether the compiled fused step ``key`` holds a Pallas TPU call.
     Lowers with the live server state; nothing runs, nothing is
     donated, and the trace cache is hit (no retrace)."""
-    step = ex._steps[key]
     K, M = ex.K, len(strategy.counts)
-    args = (strategy.w_global, strategy.tier_models,
-            np.array([0, 0, K], np.int32), np.zeros(K, np.int32),
-            np.zeros(K, np.float32), np.zeros(M, np.float32))
+    buf, layout = pack_round({
+        "ints": np.array([0, 0, K], np.int32), "pid": np.zeros(K, np.int32),
+        "w_intra": np.zeros(K, np.float32),
+        "w_cross": np.zeros(M, np.float32)})
+    step = ex._steps[key, layout]
+    args = (strategy.w_global, strategy.tier_models, buf)
     return "tpu_custom_call" in step.lower(*args).compile().as_text()
 
 
@@ -197,15 +200,17 @@ def phase_a(c: Checks):
     # (after the trace check: a round fed its batch as data traces the
     # step once more)
     codec = transport.get_codec("polyline:4")
-    step = ex._fedat_step(codec, True)
     inp = _round_inputs(env)
-    data = {k: env.train[k][inp["pid"]] for k in ("x", "y", "mask")}
+    buf, layout = pack_round({
+        "ints": inp["ints"],
+        **{k: env.train[k][inp["pid"]] for k in ("x", "y", "mask")},
+        "w_intra": inp["w_intra"], "w_cross": inp["cw"]})
+    step = ex._fedat_step(codec, True, layout)
 
     def on(dev, precision="default"):
         put = lambda t: jax.device_put(t, dev)  # noqa: E731
         with jax.default_matmul_precision(precision):
-            w, _ = step(put(inp["w"]), put(inp["tiers"]), put(inp["ints"]),
-                        put(data), put(inp["w_intra"]), put(inp["cw"]))
+            w, _ = step(put(inp["w"]), put(inp["tiers"]), put(buf))
         return jax.tree.map(np.asarray, w)
 
     cpu = on(jax.devices("cpu")[0])

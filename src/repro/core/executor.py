@@ -45,10 +45,22 @@ each step takes the event's ``seed`` and live count ``n_live`` as int32
 data and derives the padded (K, 2) key array in the graph
 (:func:`_round_keys`): the K-way split, with rows ``>= n_live`` zeroed.
 Padded slots train on zero keys but carry zero weight.  The live count
-is traced, so it never retraces the step.  The round's integers (FedAT's
-tier slot ``m``, ``seed``, ``n_live``) travel as one int32 vector: each
-host array passed to a jitted call is a host-to-device copy of its own
-(0.1–0.35 ms on a TPU v5e host).
+is traced, so it never retraces the step.
+
+One host buffer per FedAT round: each host array handed to the device is
+a transfer of its own (0.1–0.35 ms on a TPU v5e host, whatever its size),
+so :meth:`RoundExecutor.fedat_round` packs everything the round sends —
+the header ``(m, seed, n_live)``, the round's data (the padded id vector
+on the resident planes, the streamed ``x``, ``y``, ``mask`` on the
+streaming plane), the Eq. 4 and Eq. 3 weights and the gated step's
+poison mask — into one int32 vector (:func:`pack_round`) and hands it
+over in one ``jax.device_put``.  The step unpacks it with static offsets
+(:func:`unpack_round`): slices, reshapes and bitcasts, all exact, so
+every value the step sees is the one the host packed.  Float payloads
+travel as their bits (``view(np.int32)`` on the host,
+``lax.bitcast_convert_type`` in the graph); no float operation touches
+the carrier.  ``h2d_transfers`` counts the host-to-device transfers the
+round paths issue: one per FedAT round on every plane.
 
 Trace accounting: every fused step bumps ``trace_counts[step_key]`` at
 trace time (a Python side effect inside the jitted function body), which
@@ -100,6 +112,51 @@ def _round_keys(seed, n_live, K: int) -> jax.Array:
         "is split(key, K)[:n] == split(key, n), the seed loops' keys")
     keys = jax.random.split(jax.random.PRNGKey(seed), K)
     return jnp.where(jnp.arange(K)[:, None] < n_live, keys, 0)
+
+
+#: one field of a packed round buffer: (name, shape, host dtype)
+Field = Tuple[str, Tuple[int, ...], np.dtype]
+
+
+def pack_round(fields: Dict[str, np.ndarray]
+               ) -> Tuple[np.ndarray, Tuple[Field, ...]]:
+    """Pack a round's host arrays into one int32 carrier, in order.
+
+    Returns ``(buf, layout)``: ``layout`` is the static description
+    :func:`unpack_round` reads the fields back with.  A 4-byte payload
+    (int32, float32) travels as its bits; a bool travels as 0 / 1."""
+    layout = tuple((k, a.shape, a.dtype) for k, a in fields.items())
+    buf = np.empty(sum(a.size for a in fields.values()), np.int32)
+    o = 0
+    for k, a in fields.items():
+        flat = a.reshape(-1)
+        if a.dtype == np.bool_:
+            buf[o:o + a.size] = flat
+        elif a.dtype.itemsize == 4:
+            buf[o:o + a.size] = flat.view(np.int32)
+        else:
+            raise TypeError(f"round field {k!r}: {a.dtype} is neither bool "
+                            "nor a 4-byte type")
+        o += a.size
+    return buf, layout
+
+
+def unpack_round(buf: jax.Array, layout: Tuple[Field, ...]
+                 ) -> Dict[str, jax.Array]:
+    """The fields of a :func:`pack_round` buffer inside a traced step:
+    static slices, reshapes and bitcasts, so each field's bits are the
+    host's."""
+    out, o = {}, 0
+    for name, shape, dtype in layout:
+        n = int(np.prod(shape, dtype=np.int64))
+        seg = jax.lax.slice_in_dim(buf, o, o + n).reshape(shape)
+        if dtype == np.bool_:
+            seg = seg != 0
+        elif dtype != np.int32:
+            seg = jax.lax.bitcast_convert_type(seg, dtype)
+        out[name] = seg
+        o += n
+    return out
 
 
 def _pin(tree: Any) -> Any:
@@ -163,6 +220,9 @@ class RoundExecutor:
         #: high-water mark of the streamed per-round batch bytes (0 until
         #: a streaming round runs; SimEnv.data_plane_bytes reads it)
         self.stream_bytes = 0
+        #: host-to-device transfers issued by the round paths: each host
+        #: array handed to the device is one (a FedAT round makes one)
+        self.h2d_transfers = 0
         self._steps: Dict[tuple, Any] = {}
         #: step key -> number of times the step body was traced; a fixed-
         #: shape step traces exactly once per configuration.
@@ -198,31 +258,52 @@ class RoundExecutor:
 
     def _select(self, data):
         """Client rows for the round: an in-graph gather over the resident
-        train stacks when ``data`` is the padded id vector, or the
-        streamed batch itself when ``data`` is the materialized dict
-        (streaming population plane).  A batch passed as a jit argument
-        is bitwise-identical input to the in-graph gather of the same
-        rows, so the two planes share one step body
-        (tests/test_population.py pins the parity)."""
-        if isinstance(data, dict):
-            return data
-        stacks = self.env.train_dev
-        return {k: jnp.take(stacks[k], data, axis=0)
-                for k in ("x", "y", "mask")}
+        train stacks when ``data`` holds the padded id vector ``pid``, or
+        the streamed rows ``x``, ``y``, ``mask`` it holds (streaming
+        population plane).  A batch passed as step input is
+        bitwise-identical input to the in-graph gather of the same rows,
+        so the two planes share one step body (tests/test_population.py
+        pins the parity)."""
+        if "pid" in data:
+            stacks = self.env.train_dev
+            return {k: jnp.take(stacks[k], data["pid"], axis=0)
+                    for k in ("x", "y", "mask")}
+        return {k: data[k] for k in ("x", "y", "mask")}
 
-    def _round_data(self, pid: np.ndarray):
-        """What the fused step selects from: the padded id vector
-        (resident planes) or the host-materialized padded batch
-        (streaming plane).  Padded dead slots repeat a live id, so the
-        streamed batch repeats that client's rows — the same selection
-        the resident gather produces, behind a zero Eq. 4 weight."""
+    def _round_batch(self, pid: np.ndarray) -> Dict[str, np.ndarray]:
+        """What the fused step selects from, on the host: ``{"pid"}``,
+        the padded id vector (resident planes), or the host-materialized
+        padded batch ``{x, y, mask}`` (streaming plane).  Padded dead
+        slots repeat a live id, so the streamed batch repeats that
+        client's rows — the same selection the resident gather produces,
+        behind a zero Eq. 4 weight."""
         if not self.streaming:
-            return pid
+            return {"pid": pid}
         batch = self.env.population.materialize(pid)
         self.stream_bytes = max(self.stream_bytes,
                                 sum(a.nbytes for a in batch.values()))
+        return batch
+
+    def _round_data(self, pid: np.ndarray):
+        """:meth:`_round_batch` for the rounds that pass their inputs
+        array by array (FedAvg, FedAsync, topology): the streamed batch
+        goes to the device as three transfers."""
+        batch = self._round_batch(pid)
+        if not self.streaming:
+            return batch
         with jax.profiler.TraceAnnotation("repro.round.h2d"):
+            self.h2d_transfers += len(batch)
             return {k: jnp.asarray(v) for k, v in batch.items()}
+
+    def _launch(self, step, state: tuple, args: tuple):
+        """Call a round step on the server ``state`` and the round's
+        ``args``, counting the host values among ``args``: each is a
+        host-to-device transfer of its own."""
+        self.h2d_transfers += sum(
+            isinstance(a, (np.ndarray, np.generic, int, float))
+            for a in jax.tree.leaves(args))
+        with jax.profiler.TraceAnnotation("repro.round.launch"):
+            return step(*state, *args)
 
     def _pad_topology(self, ids_edges):
         """Per-edge live id lists -> the flat (E*K_edge,) padded id
@@ -266,6 +347,7 @@ class RoundExecutor:
         for e, n in enumerate(counts):
             out[e * Ke:e * Ke + n] = keys[off:off + n]
             off += n
+        self.h2d_transfers += 1
         return jnp.asarray(out)
 
     # ------------------------------------------------------------------
@@ -335,27 +417,30 @@ class RoundExecutor:
                     ("tiers",) + (None,) * (leaf.ndim - 1), self.mesh)),
             tier_models)
 
-    def _fedat_step_sharded(self, codec, use_prox: bool):
+    def _fedat_step_sharded(self, codec, use_prox: bool, layout):
         self._check_in_graph(codec)
         key = ("fedat", codec.name, use_prox, f"data{self.D}") + self._tag
-        if key in self._steps:
-            return self._steps[key]
+        if (key, layout) in self._steps:
+            return self._steps[key, layout]
         env = self.env
         update = env.update_fn_raw if use_prox else env.update_fn_noprox_raw
         train = self._train_psum(update, codec.lossy)
 
-        def step(w_global, tier_models, ints, data, w_intra, w_cross):
+        def step(w_global, tier_models, buf):
             self._bump(key)
-            m, keys = ints[0], _round_keys(ints[1], ints[2], self.K)
+            r = unpack_round(buf, layout)
+            m, keys = r["ints"][0], _round_keys(r["ints"][1], r["ints"][2],
+                                                self.K)
             tier_model = _pin(
-                train(w_global, self._select(data), keys, w_intra))
+                train(w_global, self._select(r), keys, r["w_intra"]))
             tier_models = self._tier_place(jax.tree.map(
                 lambda s, nw: s.at[m].set(nw), tier_models, tier_model))
-            w_global = aggregation.weighted_average(tier_models, w_cross)
+            w_global = aggregation.weighted_average(tier_models,
+                                                    r["w_cross"])
             return w_global, tier_models
 
-        self._steps[key] = jax.jit(step, donate_argnums=(0, 1))
-        return self._steps[key]
+        self._steps[key, layout] = jax.jit(step, donate_argnums=(0, 1))
+        return self._steps[key, layout]
 
     def _fedavg_step_sharded(self, codec=None):
         self._check_in_graph(codec)
@@ -376,32 +461,37 @@ class RoundExecutor:
         return self._steps[key]
 
     # -- single-device steps (and the D == 1 path under any mesh) -------
-    def _fedat_step(self, codec, use_prox: bool):
+    def _fedat_step(self, codec, use_prox: bool, layout):
+        """The FedAT round step for a :func:`pack_round` buffer of
+        ``layout``: ``step(w_global, tier_models, buf)``."""
         if self.D > 1:
-            return self._fedat_step_sharded(codec, use_prox)
+            return self._fedat_step_sharded(codec, use_prox, layout)
         self._check_in_graph(codec)
         key = ("fedat", codec.name, use_prox) + self._tag
-        if key in self._steps:
-            return self._steps[key]
+        if (key, layout) in self._steps:
+            return self._steps[key, layout]
         env = self.env
         update = env.update_fn_raw if use_prox else env.update_fn_noprox_raw
         lossy = codec.lossy
 
-        def step(w_global, tier_models, ints, data, w_intra, w_cross):
+        def step(w_global, tier_models, buf):
             self._bump(key)
-            m, keys = ints[0], _round_keys(ints[1], ints[2], self.K)
+            r = unpack_round(buf, layout)
+            m, keys = r["ints"][0], _round_keys(r["ints"][1], r["ints"][2],
+                                                self.K)
             w_sent = _pin(lossy(w_global))
-            client_params, _ = update(w_sent, self._select(data), keys)
+            client_params, _ = update(w_sent, self._select(r), keys)
             client_params = _pin(lossy(_pin(client_params)))
             tier_model = _pin(
-                aggregation.weighted_average(client_params, w_intra))
+                aggregation.weighted_average(client_params, r["w_intra"]))
             tier_models = jax.tree.map(lambda s, nw: s.at[m].set(nw),
                                        tier_models, tier_model)
-            w_global = aggregation.weighted_average(tier_models, w_cross)
+            w_global = aggregation.weighted_average(tier_models,
+                                                    r["w_cross"])
             return w_global, tier_models
 
-        self._steps[key] = jax.jit(step, donate_argnums=(0, 1))
-        return self._steps[key]
+        self._steps[key, layout] = jax.jit(step, donate_argnums=(0, 1))
+        return self._steps[key, layout]
 
     def _fedat_topology_step(self, codecs, use_prox: bool):
         """One fused hierarchical silo round (DESIGN.md §Topology-plane):
@@ -478,7 +568,7 @@ class RoundExecutor:
         self._steps[key] = jax.jit(step, donate_argnums=(1, 2))
         return self._steps[key]
 
-    def _fedat_step_gated(self, codec, use_prox: bool, gate):
+    def _fedat_step_gated(self, codec, use_prox: bool, gate, layout):
         """FedAT round step with the fault plane's server-side validation
         gate (core/steps.py) spliced in after the uplink decode: poison
         injection (NaN uplinks) → non-finite zero-weighting + renormalize
@@ -494,24 +584,26 @@ class RoundExecutor:
         self._check_in_graph(codec)
         key = ("fedat", codec.name, use_prox, "gate", gate.clip_norm) \
             + self._tag
-        if key in self._steps:
-            return self._steps[key]
+        if (key, layout) in self._steps:
+            return self._steps[key, layout]
         from repro.core import steps as fl_steps
         env = self.env
         update = env.update_fn_raw if use_prox else env.update_fn_noprox_raw
         lossy = codec.lossy
         clip = float(gate.clip_norm)
 
-        def step(w_global, tier_models, ints, data, w_intra, w_cross,
-                 poison):
+        def step(w_global, tier_models, buf):
             self._bump(key)
-            m, keys = ints[0], _round_keys(ints[1], ints[2], self.K)
+            r = unpack_round(buf, layout)
+            m, keys = r["ints"][0], _round_keys(r["ints"][1], r["ints"][2],
+                                                self.K)
             w_sent = _pin(lossy(w_global))
-            client_params, _ = update(w_sent, self._select(data), keys)
+            client_params, _ = update(w_sent, self._select(r), keys)
             client_params = _pin(lossy(_pin(client_params)))
-            client_params = fl_steps.poison_updates(client_params, poison)
+            client_params = fl_steps.poison_updates(client_params,
+                                                    r["poison"])
             client_params, w_ok, any_ok = fl_steps.gate_updates(
-                client_params, w_intra, w_sent, clip)
+                client_params, r["w_intra"], w_sent, clip)
             tier_model = _pin(
                 aggregation.weighted_average(client_params, w_ok))
             prev = jax.tree.map(lambda s: s[m], tier_models)
@@ -519,11 +611,12 @@ class RoundExecutor:
                 lambda nw, p: jnp.where(any_ok, nw, p), tier_model, prev)
             tier_models = jax.tree.map(lambda s, nw: s.at[m].set(nw),
                                        tier_models, tier_model)
-            w_global = aggregation.weighted_average(tier_models, w_cross)
+            w_global = aggregation.weighted_average(tier_models,
+                                                    r["w_cross"])
             return w_global, tier_models
 
-        self._steps[key] = jax.jit(step, donate_argnums=(0, 1))
-        return self._steps[key]
+        self._steps[key, layout] = jax.jit(step, donate_argnums=(0, 1))
+        return self._steps[key, layout]
 
     def _fedavg_step(self, codec=None):
         """``codec=None`` is the paper's raw-f32 baseline link and keeps the
@@ -642,22 +735,29 @@ class RoundExecutor:
         UpdateGate`) a distinct gated step is compiled; ``poison`` is the
         (K,) bool uplink-poison mask over the padded client axis (None =
         no poisoning this round).
+
+        Everything the round sends goes to the device as one packed host
+        buffer (:func:`pack_round`), one transfer on every plane.
         """
         with jax.profiler.TraceAnnotation("repro.round"):
             pid, ns = self._pad_ids(ids)
-            data = self._round_data(pid)
+            fields = self._round_batch(pid)
             with jax.profiler.TraceAnnotation("repro.round.keys"):
                 ints = np.array([m, seed, len(ids)], np.int32)
-            if gate is None:
-                step, extra = self._fedat_step(codec, use_prox), ()
-            else:
-                step = self._fedat_step_gated(codec, use_prox, gate)
-                extra = (np.zeros(self.K, bool) if poison is None
-                         else poison,)
-            w_intra = aggregation.client_weights_host(ns)
-            with jax.profiler.TraceAnnotation("repro.round.launch"):
-                return step(w_global, tier_models, ints, data, w_intra,
-                            cross_weights, *extra)
+            fields = {"ints": ints, **fields,
+                      "w_intra": aggregation.client_weights_host(ns),
+                      "w_cross": np.asarray(cross_weights, np.float32)}
+            if gate is not None:
+                fields["poison"] = (np.zeros(self.K, bool) if poison is None
+                                    else np.asarray(poison, bool))
+            with jax.profiler.TraceAnnotation("repro.round.h2d"):
+                buf, layout = pack_round(fields)
+                buf = jax.device_put(buf)
+                self.h2d_transfers += 1
+            step = (self._fedat_step(codec, use_prox, layout) if gate is None
+                    else self._fedat_step_gated(codec, use_prox, gate,
+                                                layout))
+            return self._launch(step, (w_global, tier_models), (buf,))
 
     def fedat_topology_round(self, w_global, silo_models, dispatch, s: int,
                              ids_edges, seed: int, *, codecs,
@@ -689,9 +789,9 @@ class RoundExecutor:
             with jax.profiler.TraceAnnotation("repro.round.keys"):
                 keys = self._pad_topology_keys(seed, counts)
             step = self._fedat_topology_step(codecs, use_prox)
-            with jax.profiler.TraceAnnotation("repro.round.launch"):
-                return step(w_global, silo_models, dispatch, np.int32(s),
-                            data, w_intra, w_edge, cross_weights, keys)
+            return self._launch(
+                step, (w_global, silo_models, dispatch),
+                (np.int32(s), data, w_intra, w_edge, cross_weights, keys))
 
     def fedavg_round(self, w, ids: np.ndarray, seed: int, *, codec=None,
                      gate=None, poison=None):
@@ -712,9 +812,9 @@ class RoundExecutor:
                 step = self._fedavg_step_gated(codec, gate)
                 extra = (np.zeros(self.K, bool) if poison is None
                          else poison,)
-            w_intra = aggregation.client_weights_host(ns)
-            with jax.profiler.TraceAnnotation("repro.round.launch"):
-                return step(w, data, w_intra, ints, *extra)
+            return self._launch(
+                step, (w,),
+                (data, aggregation.client_weights_host(ns), ints) + extra)
 
     def fedasync_round(self, w, client: int, a_eff: float, seed: int, *,
                        codec=None):
@@ -728,6 +828,6 @@ class RoundExecutor:
             with jax.profiler.TraceAnnotation("repro.round.keys"):
                 key = np.int32(seed)
             data = self._round_data(np.asarray([client], np.int32))
-            with jax.profiler.TraceAnnotation("repro.round.launch"):
-                return step(w, data, np.float32(1.0 - a_eff),
-                            np.float32(a_eff), key)
+            return self._launch(
+                step, (w,),
+                (data, np.float32(1.0 - a_eff), np.float32(a_eff), key))

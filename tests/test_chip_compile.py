@@ -95,19 +95,25 @@ def test_fused_cnn_round_compiles_for_v5e(one_chip):
     holds the codec kernel, and fits the chip's 16 GB."""
     from repro import api
     from repro.compress import transport
+    from repro.core.executor import pack_round
     spec = api.ExperimentSpec().with_overrides({"data.image_hw": 32})
     env = api.get_env(spec)
     ex = env.executor()
-    step = ex._fedat_step(transport.QuantizeCodec(8, interpret=False), True)
     K, M = ex.K, env.tm.n_tiers
-    data = {k: _spec(one_chip, (K,) + env.train[k].shape[1:],
-                     env.train[k].dtype) for k in ("x", "y", "mask")}
+    buf, layout = pack_round({
+        "ints": np.zeros(3, np.int32),
+        **{k: np.zeros((K,) + env.train[k].shape[1:], env.train[k].dtype)
+           for k in ("x", "y", "mask")},
+        "w_intra": np.zeros(K, np.float32),
+        "w_cross": np.zeros(M, np.float32)})
+    step = ex._fedat_step(transport.QuantizeCodec(8, interpret=False), True,
+                          layout)
     w = jax.tree.map(lambda l: _spec(one_chip, l.shape, l.dtype),
                      env.params0)
     tiers = jax.tree.map(lambda l: _spec(one_chip, (M,) + l.shape, l.dtype),
                          env.params0)
-    c = step.lower(w, tiers, _spec(one_chip, (3,), jnp.int32), data,
-                   _spec(one_chip, (K,)), _spec(one_chip, (M,))).compile()
+    c = step.lower(w, tiers,
+                   _spec(one_chip, buf.shape, jnp.int32)).compile()
     assert "tpu_custom_call" in c.as_text()
     mem = c.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9

@@ -11,11 +11,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import api
 from repro.compress import transport
 from repro.core import aggregation
 from repro.core.baselines import BaselineConfig, run_fedasync, run_fedavg, \
     run_tifl
-from repro.core.executor import _round_keys
+from repro.core.executor import _round_keys, pack_round, unpack_round
 from repro.core.fedat import FedATConfig, fake_polyline, run_fedat
 from repro.core.simulation import SimConfig, SimEnv
 
@@ -81,6 +82,98 @@ def test_distinct_codecs_compile_distinct_steps(env):
     run_fedat(env, FedATConfig(total_updates=2, eval_every=2, codec="none"))
     assert ex.trace_counts[("fedat", "quantize8", True)] == 1
     assert ex.trace_counts[("fedat", "none", True)] == 1
+
+
+# ---------------------------------------------------------------------------
+# one host buffer per FedAT round
+# ---------------------------------------------------------------------------
+
+def _plane_spec(plane):
+    """A dropout-laden FedAT run: unstable clients drop out past
+    uniform(50, 400), the indexed planes add availability and completion
+    processes, and ``gated`` adds the fault plane's validation gate."""
+    over = {"data.n_clients": 48, "data.samples_per_client": 20,
+            "data.image_hw": 8, "tiers.n_tiers": 3,
+            "tiers.clients_per_round": K, "tiers.n_unstable": 24,
+            "engine.local_epochs": 1, "engine.total_updates": 40,
+            "engine.eval_every": 20}
+    if plane == "gated":
+        over.update({"faults.nan_rate": 0.5, "faults.update_clip": 25.0})
+    else:
+        over["population.plane"] = plane
+    if plane in ("stacked", "streaming"):
+        over.update({"population.availability": "bernoulli:0.9:20",
+                     "population.completion": "bernoulli:0.8:20",
+                     "population.seed": 3})
+    return api.ExperimentSpec().with_overrides(over)
+
+
+@pytest.mark.parametrize("plane", ["legacy", "stacked", "streaming",
+                                   "gated"])
+def test_one_host_transfer_per_fedat_round(plane, monkeypatch):
+    """Every committed FedAT round hands the device exactly one host
+    buffer, on every data plane and on the gated step, and a run whose
+    rounds shrink under dropout compiles each step once."""
+    run = api.build(_plane_spec(plane))
+    ex = run.env.executor()
+    assert ex.streaming == (plane == "streaming")
+    rounds = []
+    fedat_round = ex.fedat_round
+
+    def counted(w, tms, m, ids, seed, **kw):
+        before = ex.h2d_transfers
+        out = fedat_round(w, tms, m, ids, seed, **kw)
+        rounds.append((len(ids), ex.h2d_transfers - before,
+                       kw.get("gate") is not None))
+        return out
+
+    monkeypatch.setattr(ex, "fedat_round", counted)
+    run.run()
+    assert len(rounds) >= 40
+    assert [t for _, t, _ in rounds] == [1] * len(rounds)
+    assert any(n < K for n, _, _ in rounds)      # dropout-shrunken rounds
+    assert all(g == (plane == "gated") for _, _, g in rounds)
+    assert ex.trace_counts and set(ex.trace_counts.values()) == {1}
+
+
+def _f32(*bits):
+    return np.asarray(bits, np.uint32).view(np.float32)
+
+
+def test_pack_round_keeps_every_bit():
+    """Float32 payloads travel through the int32 carrier as their bits —
+    NaN payloads (quiet and signalling, either sign), -0.0, subnormals,
+    +-inf — and int32 values whose bits are float NaN patterns come back
+    as the same integers; bools come back as bools.  On the CPU
+    backend, through a jitted unpack."""
+    f = _f32(0x7FC00000, 0xFFC00000, 0x7FC00001, 0x7F800001, 0xFF800001,
+             0x7FFFFFFF, 0x80000000, 0x00000000, 0x00000001, 0x807FFFFF,
+             0x007FFFFF, 0x7F800000, 0xFF800000, 0x3FC00000, 0x7F7FFFFF,
+             0x00800000).reshape(2, 4, 2)
+    i = np.asarray([0x7FC00000, 0x7F800001, -1, -8388607, -4194304,
+                    0x7FFFFFFF, -2 ** 31, 0], np.int32)
+    fields = {"ints": i, "x": f, "mask": np.asarray([[1, 0, 1], [0, 0, 1]],
+                                                    bool),
+              "w": _f32(0x80000000, 0x00000001, 0xFFC12345)}
+    buf, layout = pack_round(fields)
+    assert buf.dtype == np.int32 and buf.shape == (8 + 16 + 6 + 3,)
+    cpu = jax.devices("cpu")[0]
+    out = jax.jit(lambda b: unpack_round(b, layout))(
+        jax.device_put(buf, cpu))
+    assert sorted(out) == sorted(fields)
+    for k, a in fields.items():
+        b = np.asarray(out[k])
+        assert b.dtype == a.dtype and b.shape == a.shape, k
+        if a.dtype == np.bool_:
+            np.testing.assert_array_equal(b, a)
+        else:
+            np.testing.assert_array_equal(b.view(np.uint32),
+                                          a.view(np.uint32))
+
+
+def test_pack_round_refuses_other_widths():
+    with pytest.raises(TypeError, match="'w'"):
+        pack_round({"w": np.zeros(3, np.float64)})
 
 
 # ---------------------------------------------------------------------------

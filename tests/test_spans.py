@@ -5,7 +5,8 @@ update carries one ``repro.event`` holding the strategy (with its
 ``alive()`` mask) and the round (materialize, H2D copy, key split,
 launch of the fused step); eval updates add ``repro.eval`` (with its
 wait for the device) and ``repro.on_eval``.  The resident plane copies
-no batch, and the spans change nothing the run computes.
+no batch (its one transfer carries ids and weights), and the spans
+change nothing the run computes.
 """
 import dataclasses
 import os
@@ -107,15 +108,23 @@ def test_eval_updates_carry_eval_wait_and_on_eval(streamed):
 
 
 def test_the_resident_plane_copies_no_batch(tmp_path):
+    """A resident round materializes no rows: its one transfer
+    (``repro.round.h2d``) carries the ids and weights, and no batch
+    bytes were streamed."""
     api.clear_env_cache()
     try:
         _, lines = _traced(_spec("stacked"), tmp_path)
+        ex = api.get_env(_spec("stacked")).executor()
     finally:
         api.clear_env_cache()
-    names = {sp[0] for spans in lines for sp in spans}
-    assert "repro.round.h2d" not in names
-    assert {"repro.round", "repro.round.keys",
-            "repro.round.launch"} <= names
+    spans = [sp for line in lines for sp in line]
+    rounds = [sp for sp in spans if sp[0] == "repro.round"]
+    assert len(rounds) == UPDATES
+    for r in rounds:
+        assert not _inside(r, spans, "repro.materialize")
+        for part in ROUND_PARTS[1:]:
+            assert len(_inside(r, spans, part)) == 1, part
+    assert ex.stream_bytes == 0
 
 
 def test_the_profiler_changes_no_metric(streamed):
